@@ -2,7 +2,8 @@
 
 Two layers:
 
-- **micro**: Pallas kernels (interpret on CPU) vs the pure-jnp reference
+- **micro**: Pallas kernels (interpreted on CPU, compiled on TPU) vs the
+  pure-jnp reference
   — anchor search, the fused seek composition, and the REMIX build
   throughput (compaction-side cost the WA accounting charges).
 - **device pipeline**: a promoted single-partition store answers a
@@ -57,14 +58,17 @@ def bench_micro(csv: CSV) -> None:
     csv.emit("kernels_remix_build", (time.perf_counter() - t0) * 1e6,
              f"{8*16384} entries")
     qk = qkeys(rng, int(keys[-1]), 1024)
+    interp = ops.interpret_for_backend()
+    mode = f"interpret={interp}"
     t = time_batched(
-        lambda q: anchor_search(remix.anchors, q, interpret=True), qk
+        lambda q: anchor_search(remix.anchors, q, interpret=interp), qk
     )
-    csv.emit("kernels_anchor_search_pallas_interp", t / 1024 * 1e6, "")
+    csv.emit("kernels_anchor_search_pallas", t / 1024 * 1e6, mode)
     t = time_batched(lambda q: anchor_search_ref(remix.anchors, q), qk)
     csv.emit("kernels_anchor_search_ref", t / 1024 * 1e6, "")
-    t = time_batched(lambda q: ops.seek(remix, runset, q, interpret=True), qk)
-    csv.emit("kernels_seek_fused_interp", t / 1024 * 1e6, "")
+    t = time_batched(lambda q: ops.seek(remix, runset, q, interpret=interp),
+                     qk)
+    csv.emit("kernels_seek_fused", t / 1024 * 1e6, mode)
 
 
 def _probe(domain: np.ndarray, rng, q: int) -> np.ndarray:

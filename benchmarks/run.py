@@ -18,6 +18,9 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma list of bench names")
     args = ap.parse_args()
 
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     from benchmarks import (
         batch_bench,
         cache_bench,

@@ -207,8 +207,10 @@ class RemixCursor:
             _, p, remix, runset, pos = self._stream
             import jax.numpy as jnp
 
+            kw = self.store._qkw() if self.store.cfg.use_kernels else {}
             keys, vals, valid = self.store._query_mod().gather_view(
-                remix, runset, jnp.asarray([pos], jnp.int32), self._width
+                remix, runset, jnp.asarray([pos], jnp.int32), self._width,
+                **kw,
             )
             v0 = np.asarray(valid)[0]
             kk = CK.unpack_u64(np.asarray(keys)[0][v0])

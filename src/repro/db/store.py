@@ -247,6 +247,13 @@ class RemixDB:
                 f"ingroup must be 'auto', 'binary' or 'vector', got {mode!r}"
             )
         self._ingroup = mode
+        # the legacy kernel path decides interpret mode once, like the
+        # device views do (raises on a backend with no kernel path)
+        self._kernel_interpret = None
+        if self.cfg.use_kernels:
+            from repro.kernels.ops import interpret_for_backend
+
+            self._kernel_interpret = interpret_for_backend()
         if self.cfg.cache_mode not in ("copy", "mmap"):
             raise ValueError(
                 f"cache_mode must be 'copy' or 'mmap', "
@@ -1501,7 +1508,7 @@ class RemixDB:
         CPU, the vectorized all-slot compare wins on TPU). ``auto`` was
         resolved once at construction; only valid modes reach seek."""
         if self.cfg.use_kernels:
-            return {}
+            return dict(interpret=self._kernel_interpret)
         return dict(ingroup=self._ingroup)
 
     def _device_view(self, p: Partition):

@@ -3,11 +3,18 @@
 The CPU paper binary-searches the anchor index per query. Branchy binary
 search is hostile to the VPU (data-dependent gathers); the TPU-native
 adaptation is *compare-and-count*: the target group of query q is
-``(# anchors <= q) - 1``, computed by streaming (BG, KW) anchor tiles from
-HBM through VMEM against a resident (BQ, KW) query tile and accumulating
-lexicographic compare counts. O(G) work/query but bandwidth-shaped and
-branch-free; ops.py composes a two-level (coarse→fine) hierarchy so the
+``(# anchors <= q) - 1``, computed by streaming anchor tiles from HBM
+through VMEM against a resident query tile and accumulating lexicographic
+compare counts. O(G) work/query but bandwidth-shaped and branch-free;
+``anchor_search`` composes a two-level (coarse→fine) hierarchy so the
 effective work is O(sqrt(G)) per query tile for big indexes.
+
+Tile layout: anchors are laid out word-major, ``(KW, G)``, so the anchor
+axis is the lane axis and each key word is one sublane row; queries are
+``(KW, Q, 1)``, so each query word is a sublane column. The (BQ, BG)
+compare tile is then a plain row-against-column broadcast. Key words are
+compared as int32 after flipping the sign bit, which preserves the
+unsigned order.
 """
 from __future__ import annotations
 
@@ -16,6 +23,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+_SIGN = 0x80000000
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _ordered_i32(words: jnp.ndarray) -> jnp.ndarray:
+    """uint32 words → int32 with the same order (sign-bit flip)."""
+    w = jnp.asarray(words, jnp.uint32) ^ jnp.uint32(_SIGN)
+    return jax.lax.bitcast_convert_type(w, jnp.int32)
 
 
 def _le_count_kernel(anchors_ref, queries_ref, count_ref, *, kw: int):
@@ -26,16 +45,14 @@ def _le_count_kernel(anchors_ref, queries_ref, count_ref, *, kw: int):
     def _init():
         count_ref[...] = jnp.zeros_like(count_ref)
 
-    a = anchors_ref[...]  # (BG, KW) uint32
-    qk = queries_ref[...]  # (BQ, KW) uint32
-    # lexicographic a <= q, broadcast (BQ, BG)
-    le = jnp.zeros((qk.shape[0], a.shape[0]), jnp.bool_)
-    eq = jnp.ones((qk.shape[0], a.shape[0]), jnp.bool_)
+    le = None
+    eq = None
     for w in range(kw):
-        aw = a[:, w][None, :]
-        qw = qk[:, w][:, None]
-        le = le | (eq & (aw < qw))
-        eq = eq & (aw == qw)
+        aw = anchors_ref[w:w + 1, :]  # (1, BG)
+        qw = queries_ref[w]  # (BQ, 1)
+        lt_w, eq_w = aw < qw, aw == qw  # (BQ, BG)
+        le = lt_w if le is None else le | (eq & lt_w)
+        eq = eq_w if eq is None else eq & eq_w
     le = le | eq
     count_ref[...] += jnp.sum(le.astype(jnp.int32), axis=1, keepdims=True)
 
@@ -49,27 +66,31 @@ def anchor_le_count(
     *,
     block_q: int = 256,
     block_g: int = 512,
-    interpret: bool | None = None,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Return (Q,) int32: number of anchors <= query (target group + 1)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     g, kw = anchors.shape
     q = queries.shape[0]
-    bq, bg = min(block_q, q), min(block_g, g)
-    grid = (pl.cdiv(q, bq), pl.cdiv(g, bg))
+    bg = min(block_g, _round_up(g, 128))
+    bq = min(block_q, _round_up(q, 8))
+    gp, qp = _round_up(g, bg), _round_up(q, bq)
+    # +inf pad anchors count only for the reserved all-ones key, and the
+    # clamp below restores the unpadded count for it
+    a = jnp.pad(_ordered_i32(anchors).T, ((0, 0), (0, gp - g)),
+                constant_values=jnp.iinfo(jnp.int32).max)
+    qk = jnp.pad(_ordered_i32(queries).T, ((0, 0), (0, qp - q)))[:, :, None]
     counts = pl.pallas_call(
         functools.partial(_le_count_kernel, kw=kw),
-        grid=grid,
+        grid=(qp // bq, gp // bg),
         in_specs=[
-            pl.BlockSpec((bg, kw), lambda qi, gi: (gi, 0)),
-            pl.BlockSpec((bq, kw), lambda qi, gi: (qi, 0)),
+            pl.BlockSpec((kw, bg), lambda qi, gi: (0, gi)),
+            pl.BlockSpec((kw, bq, 1), lambda qi, gi: (0, qi, 0)),
         ],
         out_specs=pl.BlockSpec((bq, 1), lambda qi, gi: (qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((qp, 1), jnp.int32),
         interpret=interpret,
-    )(anchors, queries)
-    return counts[:, 0]
+    )(a, qk)
+    return jnp.minimum(counts[:q, 0], g)
 
 
 @functools.partial(
@@ -82,7 +103,7 @@ def anchor_search(
     block_q: int = 256,
     block_g: int = 512,
     fan: int = 128,
-    interpret: bool | None = None,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Two-level compare-and-count anchor search → (Q,) target group ids.
 

@@ -107,11 +107,12 @@ class DeviceViewManager:
         slice_width: int = 64,
         registry=None,
         events=None,
-        interpret: bool | None = None,
     ):
         self.budget_bytes = int(budget_bytes)
         self.slice_width = max(1, int(slice_width))
-        self._interpret = interpret  # None: kernels auto-pick off-TPU
+        # decided once from the backend: interpreted on CPU, compiled on
+        # TPU, and any other backend raises here rather than falling back
+        self.interpret = ops.interpret_for_backend()
         self._views: "OrderedDict[int, DeviceView]" = OrderedDict()
         self._resident = 0
         if registry is None:
@@ -168,7 +169,7 @@ class DeviceViewManager:
         self._resident += nbytes
         self.events.emit(
             "device_upload", lo=int(p.lo), tier=tier, bytes=int(nbytes),
-            tables=len(p.tables),
+            tables=len(p.tables), interpret=self.interpret,
         )
         return v
 
@@ -206,7 +207,7 @@ class DeviceViewManager:
         qk = jnp.asarray(CK.pack_u64(kq))
         nw = jnp.uint32(int(now))
         fd, vd, rid_d, row_d = ops.get_live(
-            dv.remix, dv.runset, dv.exp, qk, nw, interpret=self._interpret
+            dv.remix, dv.runset, dv.exp, qk, nw, interpret=self.interpret
         )
         self._c_batches.inc()
         if dv.tier == "full":
@@ -239,7 +240,7 @@ class DeviceViewManager:
             qk = jnp.asarray(CK.pack_u64(sq))
             kd, vd, md, _, _, _ = ops.scan_live(
                 dv.remix, dv.runset, dv.exp, qk, nw, width=width,
-                interpret=self._interpret,
+                interpret=self.interpret,
             )
             self._c_batches.inc()
             if with_vals:
@@ -274,7 +275,7 @@ class DeviceViewManager:
             qk = jnp.asarray(CK.pack_u64(sq))
             return ops.scan_live(
                 dv.remix, dv.runset, dv.exp, qk, nw, width=width,
-                interpret=self._interpret,
+                interpret=self.interpret,
             )
 
         out: list = []

@@ -18,9 +18,22 @@ from repro.kernels.anchor_search import anchor_search
 from repro.kernels.selector_decode import selector_decode
 
 
+def interpret_for_backend(backend: str | None = None) -> bool:
+    """Whether the Pallas kernels run in interpret mode on ``backend``
+    (default: the attached one). Each kernel caller decides this once:
+    the CPU interprets (the test configuration), the TPU compiles, and
+    any other backend has no kernel path."""
+    backend = backend or jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
+
+
 @partial(jax.jit, static_argnames=("interpret",))
 def seek(
-    remix: Remix, runset: RunSet, queries: jnp.ndarray, interpret: bool | None = None
+    remix: Remix, runset: RunSet, queries: jnp.ndarray, *, interpret: bool
 ) -> jnp.ndarray:
     """Kernel-backed lower-bound seek; same contract as core.query.seek."""
     queries = jnp.asarray(queries, jnp.uint32)
@@ -46,7 +59,8 @@ def gather_view(
     runset: RunSet,
     pos: jnp.ndarray,
     width: int,
-    interpret: bool | None = None,
+    *,
+    interpret: bool,
 ):
     """Kernel-backed comparison-free range retrieval from view positions."""
     d = remix.d
@@ -80,13 +94,13 @@ def gather_view(
 
 
 @partial(jax.jit, static_argnames=("width", "interpret"))
-def scan(remix, runset, queries, width: int, interpret: bool | None = None):
+def scan(remix, runset, queries, width: int, *, interpret: bool):
     pos = seek(remix, runset, queries, interpret=interpret)
     return (*gather_view(remix, runset, pos, width, interpret=interpret), pos)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def get(remix, runset, queries, interpret: bool | None = None):
+def get(remix, runset, queries, *, interpret: bool):
     queries = jnp.asarray(queries, jnp.uint32)
     pos = seek(remix, runset, queries, interpret=interpret)
     keys, vals, valid = gather_view(remix, runset, pos, 1, interpret=interpret)
@@ -114,7 +128,8 @@ def gather_view_live(
     pos: jnp.ndarray,
     now: jnp.ndarray,  # () uint32 traced query-time clock
     width: int,
-    interpret: bool | None = None,
+    *,
+    interpret: bool,
 ):
     """`gather_view` with query-time liveness + (run, row) emission."""
     d = remix.d
@@ -156,8 +171,7 @@ def gather_view_live(
 
 @partial(jax.jit, static_argnames=("width", "interpret"))
 def scan_live(
-    remix, runset, exp, queries, now, width: int,
-    interpret: bool | None = None,
+    remix, runset, exp, queries, now, width: int, *, interpret: bool,
 ):
     queries = jnp.asarray(queries, jnp.uint32)
     pos = seek(remix, runset, queries, interpret=interpret)
@@ -170,7 +184,7 @@ def scan_live(
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def get_live(remix, runset, exp, queries, now, interpret: bool | None = None):
+def get_live(remix, runset, exp, queries, now, *, interpret: bool):
     queries = jnp.asarray(queries, jnp.uint32)
     pos = seek(remix, runset, queries, interpret=interpret)
     keys, vals, valid, runid, absidx = gather_view_live(

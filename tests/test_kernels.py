@@ -134,3 +134,10 @@ def test_ops_seek_get_scan_match_reference(d):
     np.testing.assert_array_equal(
         np.asarray(k1)[np.asarray(va1)], np.asarray(k2)[np.asarray(va2)]
     )
+
+
+def test_interpret_decided_from_backend():
+    assert ops.interpret_for_backend("cpu") is True
+    assert ops.interpret_for_backend("tpu") is False
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.interpret_for_backend("gpu")
