@@ -1,0 +1,8 @@
+"""write_p95_ms: 95th percentile, over every write request of the window,
+of the time from submission to acknowledgement on the client side."""
+import numpy as np
+
+
+def read(ctx):
+    lat = [d.t_done - d.t_sub for d in ctx.done if d.req.is_write]
+    return float(np.percentile(lat, 95)) * 1e3 if lat else None
