@@ -10,7 +10,7 @@ Two layers:
   256-key batch through the persistent device view
   (``device_path="on"``): seek → selector decode → run/position resolve
   → gather, all device-side, with **exactly one host sync per batch**
-  (asserted via ``repro.kernels.device_view.SYNCS``) and bit-identical
+  (asserted via the store's ``device_syncs`` counter) and bit-identical
   results to the legacy host promoted path (asserted). On a real
   accelerator backend the fused pipeline must beat the host vectorized
   path **>= 5x** at batch 256; on CPU (interpret mode — what CI runs)
@@ -38,7 +38,7 @@ from benchmarks.cache_bench import build_store
 from benchmarks.common import CSV, make_tables, qkeys, time_batched
 from repro.core.remix import build_remix
 from repro.db.store import RemixDB, RemixDBConfig
-from repro.kernels import device_view, ops
+from repro.kernels import ops
 from repro.kernels.anchor_search import anchor_search
 from repro.kernels.ref import anchor_search_ref
 
@@ -103,10 +103,11 @@ def bench_device_pipeline(root: str, domain: np.ndarray, csv: CSV) -> dict:
     assert np.array_equal(v_h[f_h], v_d[f_d]), "device/host value mismatch"
     assert len(db_d.device_views) == 1  # single-partition store, resident
 
-    s0 = device_view.SYNCS
+    c_syncs = db_d.registry.counter("device_syncs")
+    s0 = c_syncs.value
     for _ in range(ITERS):
         db_d.get_batch(probe)
-    syncs = (device_view.SYNCS - s0) / ITERS
+    syncs = (c_syncs.value - s0) / ITERS
     assert syncs == 1.0, (
         f"fused batch-{BATCH} get paid {syncs} host syncs per batch, want 1"
     )

@@ -123,7 +123,6 @@ def run_smoke(n_keys: int, *, seed: int = 0, rounds: int = 64,
     measured counters and smoke timings."""
     import jax
 
-    import repro.kernels.device_view as device_view
     from repro.db.compaction import CompactionConfig
     from repro.db.ops import Batch, Op, OpStatus
     from repro.db.sharded import route_host
@@ -197,9 +196,9 @@ def run_smoke(n_keys: int, *, seed: int = 0, rounds: int = 64,
             dev = np.array([db.mem.get(k) is None for k in q.tolist()])
             lows = [p.lo for p in db.partitions]
             want_syncs = len(np.unique(route_host(lows, q[dev])))
-            s0 = device_view.SYNCS
+            s0 = db.registry.counter("device_syncs").value
             r = submit("get", [Op.multiget(q)])[0]
-            syncs = device_view.SYNCS - s0
+            syncs = db.registry.counter("device_syncs").value - s0
             check(syncs == want_syncs, f"get batch paid {syncs} host syncs "
                   f"for {want_syncs} partitions")
             f, v = ref.get(q)

@@ -21,6 +21,14 @@ counts live entries, draining windows without materializing values'
 consumers. Because the snapshot pins its Version, iteration is immune to
 concurrent flushes: a compaction publishing a new Version never changes
 what an open cursor returns.
+
+Under an active trace each stream open is a ``cursor_seek`` span
+(``overlay_sort`` on the first, ``route``, then the device seek's
+``launch``, ``device_wait`` and ``unpack``) and each window pull a
+``cursor_window`` span (``launch``, one ``device_wait`` per fetched
+array, ``unpack``, ``merge``); the device calls count in the store's
+``cursor_seeks``, ``cursor_windows``, ``device_launches`` and
+``device_syncs``.
 """
 from __future__ import annotations
 
@@ -32,6 +40,7 @@ from repro.core import keys as CK
 from repro.db import clock
 from repro.db.memtable import entry_dead
 from repro.db.sharded import partition_spans, route_one
+from repro.obs import tracing as _tracing
 
 _MAX_WIDTH = 4096  # widening cap over tombstone/old-version runs
 
@@ -61,20 +70,11 @@ class RemixCursor:
 
     # ---------------- positioning ----------------
     def seek(self, key: int) -> "RemixCursor":
-        """Position at the lower bound of ``key`` in the merged view."""
+        """Position at the lower bound of ``key`` in the merged view. The
+        overlay's keys are sorted, the key routed and the first stream
+        opened at the first pull (:meth:`_fill`)."""
         self._start = int(key)
-        parts = self.snap.partitions
-        self._spans = partition_spans([p.lo for p in parts])
-        if self.snap.shared:
-            # the overlay is the live MemTable dict: materialize the key
-            # list under the writer lock so a concurrent put's dict
-            # resize can't tear the iteration
-            with self.store._state_lock:
-                self._okeys = sorted(self.snap.overlay)
-        else:
-            self._okeys = sorted(self.snap.overlay)
-        self._oi = bisect.bisect_left(self._okeys, self._start)
-        self._pi = route_one(parts, self._start)
+        self._okeys = None
         self._first = True
         self._stream = None
         self._width = self.base_width
@@ -168,57 +168,107 @@ class RemixCursor:
             yield item
 
     # ---------------- internals ----------------
+    def _seek_first(self) -> None:
+        """The seek proper, at the first pull: the overlay's sorted keys,
+        the partition holding the start key, and its stream."""
+        with _tracing.span("overlay_sort"):
+            if self.snap.shared:
+                # the overlay is the live MemTable dict: materialize the
+                # key list under the writer lock so a concurrent put's
+                # dict resize can't tear the iteration
+                with self.store._state_lock:
+                    self._okeys = sorted(self.snap.overlay)
+            else:
+                self._okeys = sorted(self.snap.overlay)
+            self._oi = bisect.bisect_left(self._okeys, self._start)
+        parts = self.snap.partitions
+        with _tracing.span("route"):
+            self._spans = partition_spans([p.lo for p in parts])
+            self._pi = route_one(parts, self._start)
+        if self._pi < len(parts):
+            self._open_stream()
+
     def _open_stream(self):
         """Start the table-entry stream of the current partition: one
         seek (cold: anchors + bounded CKB; promoted: jitted device seek),
         after which every window is a pure position advance."""
-        p = self.snap.partitions[self._pi]
-        lo, _ = self._spans[self._pi]
-        start = max(self._start, lo) if self._first else lo
-        self._first = False
-        self._width = self.base_width
-        if self.store._cold_ok(p):
+        with _tracing.span("route"):
+            p = self.snap.partitions[self._pi]
+            lo, _ = self._spans[self._pi]
+            start = max(self._start, lo) if self._first else lo
+            self._first = False
+            self._width = self.base_width
+            cold = self.store._cold_ok(p)
+        if cold:
             self._stream = ("cold", p, p.cold_cursor_seek(start))
             return
         import jax.numpy as jnp
 
-        remix, runset = p.index()
-        qk = jnp.asarray(CK.pack_u64(np.array([start], np.uint64)))
-        pos = int(
-            np.asarray(
-                self.store._query_mod().seek(
-                    remix, runset, qk, **self.store._qkw()
-                )
-            )[0]
-        )
-        self._stream = ["dev", p, remix, runset, pos]
+        store = self.store
+        with _tracing.span("launch"):
+            remix, runset = p.index()
+            qk = jnp.asarray(CK.pack_u64(np.array([start], np.uint64)))
+            pos_d = store._query_mod().seek(remix, runset, qk,
+                                            **store._qkw())
+            store._c_cursor_seeks.inc()
+            store._c_launches.inc()
+        (pos,) = _tracing.fetch(store._c_syncs, pos_d)
+        with _tracing.span("unpack"):
+            del qk, pos_d  # frees the device buffers
+            self._stream = ["dev", p, remix, runset, int(pos[0])]
 
     def _next_window(self):
         """One window of live table entries from the current partition.
         Returns (keys u64, vals, partition_done)."""
-        _, hi = self._spans[self._pi]
         if self._stream[0] == "cold":
             _, p, state = self._stream
             kk, vv, more = p.cold_cursor_window(
                 state, self._width,
                 prefetch_depth=self.store.cfg.prefetch_depth,
             )
+            kk, vv, clipped = self._clip(kk, vv)
         else:
             _, p, remix, runset, pos = self._stream
             import jax.numpy as jnp
 
-            kw = self.store._qkw() if self.store.cfg.use_kernels else {}
-            keys, vals, valid = self.store._query_mod().gather_view(
-                remix, runset, jnp.asarray([pos], jnp.int32), self._width,
-                **kw,
-            )
-            v0 = np.asarray(valid)[0]
-            kk = CK.unpack_u64(np.asarray(keys)[0][v0])
-            vv = np.asarray(vals)[0][v0]
+            store = self.store
+            with _tracing.span("launch"):
+                kw = store._qkw() if store.cfg.use_kernels else {}
+                keys_d, vals_d, valid_d = store._query_mod().gather_view(
+                    remix, runset, jnp.asarray([pos], jnp.int32),
+                    self._width, **kw,
+                )
+                store._c_cursor_windows.inc()
+                store._c_launches.inc()
+            # one blocking fetch per array, in the order they are read
+            (valid,) = _tracing.fetch(store._c_syncs, valid_d)
+            (keys,) = _tracing.fetch(store._c_syncs, keys_d)
+            (vals,) = _tracing.fetch(store._c_syncs, vals_d)
+            with _tracing.span("unpack"):
+                del keys_d, vals_d, valid_d  # frees the device buffers
+                v0 = valid[0]
+                kk, vv, clipped = self._clip(
+                    CK.unpack_u64(keys[0][v0]), vals[0][v0]
+                )
             more = pos + self._width < remix.n_slots
             self._stream[4] = pos + self._width
-        # clip to the partition's key range; entries at/after the next
-        # partition's lower bound mean this partition is drained
+        # adaptive widening, two cases sharing one rule: an all-invalid
+        # window (tombstone/old-version run) must grow so long dead runs
+        # cost O(log) decodes, and a productive stream grows as read-ahead
+        # — the first window stays small (seek latency), sustained
+        # consumption amortizes per-window overhead over ever larger
+        # decodes. Re-seeking scans can't do this: read-ahead is only
+        # free when the position survives the call.
+        self._width = min(self._width * 2, _MAX_WIDTH)
+        return kk, vv, clipped or not more
+
+    def _clip(self, kk, vv):
+        """A window's entries inside the partition's key range and
+        outside the snapshot's range tombstones, and whether the range's
+        end cut it (the partition is drained)."""
+        _, hi = self._spans[self._pi]
+        # entries at/after the next partition's lower bound mean this
+        # partition is drained
         cut = int(np.searchsorted(kk, np.uint64(min(hi, (1 << 64) - 1)),
                                   side="right" if hi >= 1 << 64 else "left"))
         clipped = cut < len(kk)
@@ -231,15 +281,7 @@ class RemixCursor:
             for rlo, rhi, _ in self.snap.ranges:
                 m &= ~((kk >= rlo) & (kk < rhi))
             kk, vv = kk[m], vv[m]
-        # adaptive widening, two cases sharing one rule: an all-invalid
-        # window (tombstone/old-version run) must grow so long dead runs
-        # cost O(log) decodes, and a productive stream grows as read-ahead
-        # — the first window stays small (seek latency), sustained
-        # consumption amortizes per-window overhead over ever larger
-        # decodes. Re-seeking scans can't do this: read-ahead is only
-        # free when the position survives the call.
-        self._width = min(self._width * 2, _MAX_WIDTH)
-        return kk, vv, clipped or not more
+        return kk, vv, clipped
 
     def _push(self, kk: np.ndarray, vv: np.ndarray) -> None:
         if len(kk):
@@ -293,26 +335,33 @@ class RemixCursor:
         while self._buffered < n and not self._done:
             if self._interrupt is not None:
                 self._interrupt()
+            if self._okeys is None:
+                with _tracing.span("cursor_seek"):
+                    self._seek_first()
+            elif self._stream is None and self._pi < len(parts):
+                with _tracing.span("cursor_seek"):
+                    self._open_stream()
             if self._pi >= len(parts):
                 # every partition drained: flush the overlay tail
-                self._merge_emit(
-                    np.zeros(0, np.uint64),
-                    np.zeros((0, self.vw), np.uint32),
-                    (1 << 64) - 1,
-                )
+                with _tracing.span("merge"):
+                    self._merge_emit(
+                        np.zeros(0, np.uint64),
+                        np.zeros((0, self.vw), np.uint32),
+                        (1 << 64) - 1,
+                    )
                 self._done = True
                 return
-            if self._stream is None:
-                self._open_stream()
-            kk, vv, pdone = self._next_window()
-            if pdone:
-                # partition exhausted: overlay entries below the next
-                # partition's range can all be emitted
-                bound = self._spans[self._pi][1] - 1
-                self._pi += 1
-                self._stream = None
-            elif len(kk):
-                bound = int(kk[-1])
-            else:
-                continue  # dead window mid-partition: nothing emittable
-            self._merge_emit(kk, vv, bound)
+            with _tracing.span("cursor_window"):
+                kk, vv, pdone = self._next_window()
+                if pdone:
+                    # partition exhausted: overlay entries below the next
+                    # partition's range can all be emitted
+                    bound = self._spans[self._pi][1] - 1
+                    self._pi += 1
+                    self._stream = None
+                elif len(kk):
+                    bound = int(kk[-1])
+                else:
+                    continue  # dead window mid-partition: none emittable
+                with _tracing.span("merge"):
+                    self._merge_emit(kk, vv, bound)

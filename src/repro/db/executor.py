@@ -79,9 +79,9 @@ def _status_for(e: BaseException) -> OpStatus:
 
 
 def _span(trace, name, **args):
-    """Span context when tracing, free no-op otherwise."""
+    """Span context when tracing, the shared no-op otherwise."""
     if trace is None:
-        return contextlib.nullcontext()
+        return _tracing.NULL_SPAN
     return trace.span(name, **args)
 
 
@@ -376,12 +376,12 @@ class Executor:
         fut = BatchFuture()
         results: list[OpResult | None] = [None] * len(batch.ops)
         t0 = time.monotonic()
-        ta = _tracing.now()
-        cost = self._admit(batch, deadlines, results)
+        with _span(trace, "admission") as sp:
+            cost = self._admit(batch, deadlines, results)
+        if sp is not None:
+            sp.args["bytes"] = cost
         wait_s = time.monotonic() - t0
         self._h_wait.observe(wait_s)
-        if trace is not None:
-            trace.leaf("admission", ta, _tracing.now(), bytes=cost)
         t_sub = time.monotonic()
         if all(r is not None for r in results):  # every op expired waiting
             self._finish(fut, batch, results, cost, wait_s, started=False,
@@ -519,16 +519,19 @@ class Executor:
 
     def _finish(self, fut, batch, results, cost, wait_s, started,
                 trace=None, t_sub=None) -> None:
-        self.admission.release(cost)
-        self._release_order(fut)
-        stats = self._batch_stats(batch, results, wait_s, started)
-        self._c_completed.inc()
-        self._c_deadline.inc(stats["deadline_exceeded"])
-        self._c_cancelled_ops.inc(stats["cancelled"])
-        self._c_errors.inc(stats["errors"])
-        self._c_io_errors.inc(stats["io_errors"])
-        if t_sub is not None:
-            self._h_batch.observe(time.monotonic() - t_sub)
+        # the trace ends before set_result hands it to the caller, so the
+        # wake-up of the result's waiter lies outside the ``finish`` span
+        with _span(trace, "finish"):
+            self.admission.release(cost)
+            self._release_order(fut)
+            stats = self._batch_stats(batch, results, wait_s, started)
+            self._c_completed.inc()
+            self._c_deadline.inc(stats["deadline_exceeded"])
+            self._c_cancelled_ops.inc(stats["cancelled"])
+            self._c_errors.inc(stats["errors"])
+            self._c_io_errors.inc(stats["io_errors"])
+            if t_sub is not None:
+                self._h_batch.observe(time.monotonic() - t_sub)
         if trace is not None:
             trace.finish()
             self.last_trace = trace
@@ -806,18 +809,7 @@ class Executor:
             if results[i] is None
             and self._precheck(fut, deadlines, results, [i])
         ]
-        keys: list[np.ndarray] = []
-        for i in gets:
-            keys.append(np.array([batch.ops[i].key], np.uint64))
-        for i, pos in mgets:
-            if i not in mg:
-                q = len(batch.ops[i].keys)
-                mg[i] = [np.zeros(q, bool),
-                         np.zeros((q, self.vw), np.uint32)]
-            keys.append(np.asarray(batch.ops[i].keys, np.uint64)[pos])
-        if not keys:
-            return
-        if len(gets) == 1 and not mgets and len(keys[0]) == 1:
+        if len(gets) == 1 and not mgets:
             # lone point lookup: the scalar read path (same results as the
             # batched one — tested — but with the bounded per-key byte
             # profile legacy ``db.get`` had)
@@ -833,6 +825,17 @@ class Executor:
             results[i] = OpResult(
                 status=OpStatus.OK, found=val is not None, value=val
             )
+            return
+        keys: list[np.ndarray] = []
+        for i in gets:
+            keys.append(np.array([batch.ops[i].key], np.uint64))
+        for i, pos in mgets:
+            if i not in mg:
+                q = len(batch.ops[i].keys)
+                mg[i] = [np.zeros(q, bool),
+                         np.zeros((q, self.vw), np.uint32)]
+            keys.append(np.asarray(batch.ops[i].keys, np.uint64)[pos])
+        if not keys:
             return
         qk = np.concatenate(keys)
         try:

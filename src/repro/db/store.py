@@ -67,6 +67,7 @@ from repro.db.version import Snapshot, VersionSet
 from repro.db.wal import FLAG_RANGE, FLAG_TOMB, WAL, unpack_range_hi
 from repro.io.faults import (CorruptionError, IOContext,
                              UnavailableSpanError)
+from repro.obs import tracing as _tracing
 from repro.obs.events import EventLog, NULL_EVENTS
 from repro.obs.metrics import MetricsRegistry, merge_snapshots
 
@@ -372,6 +373,17 @@ class RemixDB:
         self._c_cas_conflict = reg.counter("cas_conflict")
         self._c_ttl_dropped = reg.counter("ttl_expired_dropped")
         self._c_rtomb_drop = reg.counter("range_tombstone_drop")
+        # the device boundary of the jitted reads over ``p.index()`` and
+        # of the cursor; the device views count into the same series
+        self._c_launches = reg.counter("device_launches")
+        self._c_syncs = reg.counter("device_syncs")
+        self._c_cursor_seeks = reg.counter("cursor_seeks")
+        self._c_cursor_windows = reg.counter("cursor_windows")
+        # scans of a group that the cursor answers in scan_live's place
+        self._c_scan_fallback = {
+            r: reg.counter("scan_cursor_fallbacks", reason=r)
+            for r in ("lone", "overlay", "underfull")
+        }
         self._comp_kinds: set[str] = set()  # plan kinds seen so far
         self._h_flush = reg.histogram("db_flush_seconds")
         reg.gauge("db_memtable_entries", fn=lambda: len(self.mem))
@@ -965,11 +977,12 @@ class RemixDB:
         )
         with self._write_lock:
             seqs = np.arange(self.seq, self.seq + n, dtype=np.uint64)
-            self.wal.append_batch(keys, seqs, tombs, vals, exps=exps)
+            with _tracing.span("wal_append"):
+                self.wal.append_batch(keys, seqs, tombs, vals, exps=exps)
             # MemTable inserts take the state lock so concurrent readers
             # can materialize a stable view of the live overlay (cursor
             # seeks iterate it; dict iteration must not race a resize)
-            with self._state_lock:
+            with _tracing.span("memtable_apply"), self._state_lock:
                 self.seq = self.mem.put_batch(keys, vals, self.seq,
                                               tomb=tombs, exp=exps)
             self._c_user_bytes.inc(n * (8 + 4 * self.cfg.vw))
@@ -1460,7 +1473,7 @@ class RemixDB:
         dict instead of copying it. The pin matters — without it a
         concurrent flush could release the version and delete its files
         mid-read; a Python reference keeps objects alive, not files."""
-        with self._state_lock:
+        with _tracing.span("pin"), self._state_lock:
             v = self.versions.pin_current()
             src = (
                 self._flush_overlay
@@ -1573,28 +1586,36 @@ class RemixDB:
                 )
 
     def _get_at(self, view: Snapshot, key: int):
-        e = view.overlay.get(int(key))
-        if e is not None:
-            return None if entry_dead(e, clock.now()) else e.val
-        if view.ranges and view.covers(int(key)):
-            return None  # hidden by an unflushed range tombstone
-        if self._unavailable:
-            self._check_unavailable_point(int(key))
-        parts = view.partitions
-        p = parts[route_one(parts, int(key))]
-        if self._cold_ok(p):
+        with _tracing.span("overlay_probe"):
+            e = view.overlay.get(int(key))
+            if e is not None:
+                return None if entry_dead(e, clock.now()) else e.val
+            if view.ranges and view.covers(int(key)):
+                return None  # hidden by an unflushed range tombstone
+            if self._unavailable:
+                self._check_unavailable_point(int(key))
+        with _tracing.span("route"):
+            parts = view.partitions
+            p = parts[route_one(parts, int(key))]
+            cold = self._cold_ok(p)
+            dv = None if cold else self._device_view(p)
+        if cold:
             found, val = p.cold_get(int(key))
             return val if found else None
-        dv = self._device_view(p)
         if dv is not None:
-            f, v = self.device_views.get_batch(
-                dv, np.array([key], np.uint64), clock.now()
-            )
-            return v[0] if bool(f[0]) else None
-        remix, runset = p.index()
-        qk = jnp.asarray(CK.pack_u64(np.array([key], np.uint64)))
-        found, val = self._query_mod().get(remix, runset, qk, **self._qkw())
-        return np.asarray(val)[0] if bool(np.asarray(found)[0]) else None
+            f, v = self.device_views.get_batch(dv, [key], clock.now())
+            return v[0] if f[0] else None
+        with _tracing.span("launch"):
+            remix, runset = p.index()
+            qk = jnp.asarray(CK.pack_u64(np.array([key], np.uint64)))
+            found, val = self._query_mod().get(remix, runset, qk,
+                                               **self._qkw())
+            self._c_launches.inc()
+        (f,) = _tracing.fetch(self._c_syncs, found)
+        if not f[0]:
+            return None
+        (v,) = _tracing.fetch(self._c_syncs, val)
+        return v[0]
 
     def get_batch(self, keys) -> tuple[np.ndarray, np.ndarray]:
         """Batched point lookups. Returns (found (Q,), vals (Q,VW))."""
@@ -1607,42 +1628,52 @@ class RemixDB:
         vals = np.zeros((len(keys), self.cfg.vw), np.uint32)
         rest = []
         now = clock.now()
-        for i, k in enumerate(keys.tolist()):
-            e = view.overlay.get(k)
-            if e is not None:
-                found[i] = not entry_dead(e, now)
-                vals[i] = e.val
-            elif not (view.ranges and view.covers(k)):
-                rest.append(i)
+        with _tracing.span("overlay_probe"):
+            for i, k in enumerate(keys.tolist()):
+                e = view.overlay.get(k)
+                if e is not None:
+                    found[i] = not entry_dead(e, now)
+                    vals[i] = e.val
+                elif not (view.ranges and view.covers(k)):
+                    rest.append(i)
+            if rest and self._unavailable:
+                for i in rest:
+                    self._check_unavailable_point(int(keys[i]))
+        if not rest:
+            return found, vals
         parts = view.partitions
-        if rest and self._unavailable:
-            for i in rest:
-                self._check_unavailable_point(int(keys[i]))
-        if rest:
+        with _tracing.span("route"):
             rest = np.array(rest)
             pidx = route_host([p.lo for p in parts], keys[rest])
-            for pi in np.unique(pidx):
-                sel = rest[pidx == pi]
-                p = parts[pi]
-                if self._cold_ok(p):
-                    f, v = p.cold_get_batch(keys[sel])
-                    found[sel] = f
-                    vals[sel[f]] = v[f]
-                    continue
-                dv = self._device_view(p)
-                if dv is not None:
-                    f, v = self.device_views.get_batch(dv, keys[sel], now)
-                    found[sel] = f
-                    vals[sel] = v
-                    continue
+        for pi in np.unique(pidx):
+            sel = rest[pidx == pi]
+            p = parts[pi]
+            with _tracing.span("route"):
+                cold = self._cold_ok(p)
+                dv = None if cold else self._device_view(p)
+            if cold:
+                f, v = p.cold_get_batch(keys[sel])
+                found[sel] = f
+                vals[sel[f]] = v[f]
+                continue
+            if dv is not None:
+                f, v = self.device_views.get_batch(dv, keys[sel], now)
+                found[sel] = f
+                vals[sel] = v
+                continue
+            with _tracing.span("launch"):
                 remix, runset = p.index()
                 kq = keys[sel]
                 pad = _pow2pad(len(kq))
                 kq = np.pad(kq, (0, pad - len(kq)))
                 qk = jnp.asarray(CK.pack_u64(kq))
-                f, v = self._query_mod().get(remix, runset, qk, **self._qkw())
-                found[sel] = np.asarray(f)[: len(sel)]
-                vals[sel] = np.asarray(v)[: len(sel)]
+                f, v = self._query_mod().get(remix, runset, qk,
+                                             **self._qkw())
+                self._c_launches.inc()
+            (f,) = _tracing.fetch(self._c_syncs, f)
+            found[sel] = f[: len(sel)]
+            (v,) = _tracing.fetch(self._c_syncs, v)
+            vals[sel] = v[: len(sel)]
         return found, vals
 
     def scan(self, start_key: int, n: int) -> tuple[np.ndarray, np.ndarray]:
@@ -1709,21 +1740,41 @@ class RemixDB:
         ``interrupts`` checker fired mid-scan (deadline/cancel) — the
         executor converts it to a per-op status.
         """
-        starts = np.asarray(starts, np.uint64)
-        q = len(starts)
-        if self._unavailable:
-            for s in starts.tolist():
-                self._check_unavailable_scan(int(s))
-        checks = interrupts if interrupts is not None else [None] * q
-        ns = np.zeros(q, np.int64) + np.asarray(n, np.int64)
-        empty_v = np.zeros((0, self.cfg.vw), np.uint32)
-        empty_row = (np.zeros(0, np.uint64), empty_v if with_vals else None)
-        out: list = [None] * q
-        act = ns > 0
-        for qi in np.flatnonzero(~act):
-            out[qi] = empty_row
-        if not act.any():
-            return out
+        # route: which queries are live, and which path answers each
+        with _tracing.span("route"):
+            starts = np.asarray(starts, np.uint64)
+            q = len(starts)
+            if self._unavailable:
+                for s in starts.tolist():
+                    self._check_unavailable_scan(int(s))
+            checks = interrupts if interrupts is not None else [None] * q
+            ns = np.zeros(q, np.int64) + np.asarray(n, np.int64)
+            empty_v = np.zeros((0, self.cfg.vw), np.uint32)
+            empty_row = (np.zeros(0, np.uint64),
+                         empty_v if with_vals else None)
+            out: list = [None] * q
+            act = ns > 0
+            for qi in np.flatnonzero(~act):
+                out[qi] = empty_row
+            if not act.any():
+                return out
+            # a lone scan keeps the legacy streaming profile: the cursor
+            # path pipelines value/tomb blocks ahead (Fig 10,
+            # prefetch_depth) — the batched window path instead
+            # coalesces across queries, which only wins with > 1 scan
+            # sharing granules. Batches over a non-empty overlay
+            # (entries or unflushed range tombstones) merge per query
+            # through the cursor too.
+            by_cursor = q == 1 or view.overlay or view.ranges
+            if by_cursor:
+                self._c_scan_fallback["lone" if q == 1 else "overlay"].inc(
+                    int(act.sum())
+                )
+            else:
+                parts = view.partitions
+                spans = partition_spans([p.lo for p in parts])
+                pidx = route_host([p.lo for p in parts], starts)
+                widths = ns + np.maximum(8, ns // 2)
 
         def row_fallback(qi):
             try:
@@ -1734,21 +1785,11 @@ class RemixDB:
                 return e
             return kk, (vv if with_vals else None)
 
-        # a lone scan keeps the legacy streaming profile: the cursor
-        # path pipelines value/tomb blocks ahead (Fig 10, prefetch_depth)
-        # — the batched window path instead coalesces across queries,
-        # which only wins with > 1 scan sharing granules. Batches over a
-        # non-empty overlay (entries or unflushed range tombstones)
-        # merge per query through the cursor too.
-        if q == 1 or view.overlay or view.ranges:
+        if by_cursor:
             return [
                 out[qi] if out[qi] is not None else row_fallback(qi)
                 for qi in range(q)
             ]
-        parts = view.partitions
-        spans = partition_spans([p.lo for p in parts])
-        pidx = route_host([p.lo for p in parts], starts)
-        widths = ns + np.maximum(8, ns // 2)
         for pi in np.unique(pidx[act]):
             sel = np.flatnonzero((pidx == pi) & act)
             p = parts[pi]
@@ -1759,11 +1800,15 @@ class RemixDB:
                 m = kk < hi  # clip to the partition's key span
                 kk = kk[m][:nn]
                 if len(kk) < nn:
+                    self._c_scan_fallback["underfull"].inc()
                     out[qi] = row_fallback(qi)
                     return
                 out[qi] = (kk, vv[m][:nn] if with_vals else None)
 
-            if self._cold_ok(p):
+            with _tracing.span("route"):
+                cold = self._cold_ok(p)
+                dv = None if cold else self._device_view(p)
+            if cold:
                 # per-query widths: the coalesced fetch set merges row
                 # windows across different n values (shared granules)
                 for qi, (kk, vv, _) in zip(
@@ -1775,7 +1820,6 @@ class RemixDB:
             # shape-stability); max width over the group, per-query n
             # clipping keeps results bit-identical to per-n groups
             width = int(widths[sel].max())
-            dv = self._device_view(p)
             if dv is not None:
                 for qi, (kk, vv) in zip(
                     sel,
@@ -1786,22 +1830,28 @@ class RemixDB:
                 ):
                     emit_row(qi, kk, vv)
                 continue
-            remix, runset = p.index()
-            sq = starts[sel]
-            pad = _pow2pad(len(sq))
-            sq = np.pad(sq, (0, pad - len(sq)))
-            qk = jnp.asarray(CK.pack_u64(sq))
-            kw = dict(self._qkw())
-            if not self.cfg.use_kernels:
-                # skip the value gather (XLA dead-code-eliminates it)
-                # when the caller only needs keys, e.g. scan_batch
-                kw["with_vals"] = with_vals
-            keys, vals, valid, _ = self._query_mod().scan(
-                remix, runset, qk, width=width, **kw
-            )
-            keys = CK.unpack_u64(np.asarray(keys))[: len(sel)]
-            valid = np.asarray(valid)[: len(sel)]
-            vals = None if vals is None else np.asarray(vals)[: len(sel)]
+            with _tracing.span("launch"):
+                remix, runset = p.index()
+                sq = starts[sel]
+                pad = _pow2pad(len(sq))
+                sq = np.pad(sq, (0, pad - len(sq)))
+                qk = jnp.asarray(CK.pack_u64(sq))
+                kw = dict(self._qkw())
+                if not self.cfg.use_kernels:
+                    # skip the value gather (XLA dead-code-eliminates it)
+                    # when the caller only needs keys, e.g. scan_batch
+                    kw["with_vals"] = with_vals
+                keys, vals, valid, _ = self._query_mod().scan(
+                    remix, runset, qk, width=width, **kw
+                )
+                self._c_launches.inc()
+            (keys,) = _tracing.fetch(self._c_syncs, keys)
+            (valid,) = _tracing.fetch(self._c_syncs, valid)
+            if vals is not None:
+                (vals,) = _tracing.fetch(self._c_syncs, vals)
+            keys = CK.unpack_u64(keys)[: len(sel)]
+            valid = valid[: len(sel)]
+            vals = None if vals is None else vals[: len(sel)]
             for row, qi in enumerate(sel):
                 v = vals[row][valid[row]] if vals is not None else None
                 emit_row(qi, keys[row][valid[row]], v)

@@ -44,6 +44,7 @@ import numpy as np
 from repro.io.checksum import crc32c
 from repro.io.faults import NULL_IO, CorruptionError
 from repro.obs import metrics as _metrics
+from repro.obs import tracing as _tracing
 
 BLOCK = 4096
 # 1-bit epoch in byte 0 + u16 record count + u32 CRC32C of the record
@@ -233,7 +234,7 @@ class WAL:
     def _fsync(self):
         """fsync the log file if blocks were written since the last one."""
         if self._dirty:
-            with open(self.path, "rb") as f:
+            with _tracing.span("wal_sync"), open(self.path, "rb") as f:
                 self.ioctx.check_fsync(self.path)
                 os.fsync(f.fileno())
             self._dirty = False

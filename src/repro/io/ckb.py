@@ -288,11 +288,10 @@ class CKBReader:
             )
             if len(todo):
                 tr = _tracing.current()
-                t0 = _tracing.now() if tr is not None else 0.0
-                keys, _ = self._decode_intervals_uncached(todo)
-                if tr is not None:
-                    tr.leaf("ckb_decode", t0, _tracing.now(),
-                            intervals=len(todo), rows=int(len(todo)) * ii)
+                with (_tracing.NULL_SPAN if tr is None else
+                      tr.span("ckb_decode", intervals=len(todo),
+                              rows=int(len(todo)) * ii)):
+                    keys, _ = self._decode_intervals_uncached(todo)
                 for r, j in enumerate(todo.tolist()):
                     memo[j] = keys[r]
             out = np.empty((len(js), ii), np.uint64)

@@ -270,8 +270,6 @@ class SSTableReader:
         :class:`CorruptionError` pinned to this file/section/granule —
         corruption is never retried and never cached.
         """
-        tr = _tracing.current()
-        t0 = _tracing.now() if tr is not None else 0.0
         bb = self.block_bytes
         lo = self._data_start + idx * bb
         hi = min(lo + bb, self._data_end)
@@ -282,12 +280,14 @@ class SSTableReader:
             f.seek(lo)
             return io.mutate_read(self.path, lo, f.read(hi - lo))
 
-        chunk = io.run("block", attempt)
-        if crc32c(chunk) != int(self._crcs[idx]):
-            raise CorruptionError(self.path, self.block_section(idx), idx)
+        tr = _tracing.current()
+        with (_tracing.NULL_SPAN if tr is None else
+              tr.span("disk_read", bytes=hi - lo, block=idx)):
+            chunk = io.run("block", attempt)
+            if crc32c(chunk) != int(self._crcs[idx]):
+                raise CorruptionError(self.path, self.block_section(idx),
+                                      idx)
         self.disk_bytes_read += hi - lo
-        if tr is not None:
-            tr.leaf("disk_read", t0, _tracing.now(), bytes=hi - lo, block=idx)
         return chunk
 
     def _mmap_block(self, idx: int) -> memoryview:
@@ -304,22 +304,22 @@ class SSTableReader:
         view = memoryview(self._mm)[lo:hi]
         if idx not in self._verified:
             tr = _tracing.current()
-            t0 = _tracing.now() if tr is not None else 0.0
             io = self._io
-            io.run("mmap", lambda: io.check_read(self.path))
-            # verify against the (possibly fault-mutated) bytes: the CRC
-            # pass must see what the injected disk would have served
-            probe = (
-                io.mutate_read(self.path, lo, bytes(view))
-                if io.has_read_mutations(self.path) else view
-            )
-            if crc32c(probe) != int(self._crcs[idx]):
-                raise CorruptionError(self.path, self.block_section(idx), idx)
+            with (_tracing.NULL_SPAN if tr is None else
+                  tr.span("disk_read", bytes=hi - lo, block=idx, mmap=True)):
+                io.run("mmap", lambda: io.check_read(self.path))
+                # verify against the (possibly fault-mutated) bytes: the
+                # CRC pass must see what the injected disk would have
+                # served
+                probe = (
+                    io.mutate_read(self.path, lo, bytes(view))
+                    if io.has_read_mutations(self.path) else view
+                )
+                if crc32c(probe) != int(self._crcs[idx]):
+                    raise CorruptionError(self.path,
+                                          self.block_section(idx), idx)
             self._verified.add(idx)
             self.disk_bytes_read += hi - lo
-            if tr is not None:
-                tr.leaf("disk_read", t0, _tracing.now(),
-                        bytes=hi - lo, block=idx, mmap=True)
         return view
 
     def _block_loader(self, idx: int):

@@ -29,9 +29,15 @@ lifecycle (``retain`` drops views whose partition left every live
 Version). Views hold a strong reference to their partition, so a view
 can never alias a recycled ``id()``.
 
-Host sync points are counted in the module-level ``SYNCS`` counter —
+Every read path crosses the device boundary through the same three
+steps, each a span under an active trace and counted in the store's
+registry: ``launch`` (pack the queries, copy them and the ``now`` scalar
+to the device, dispatch the jitted call; ``device_launches``),
+``device_wait`` (:func:`repro.obs.tracing.fetch`, one blocking
+device→host transfer; ``device_syncs``) and ``unpack`` (host work on
+the fetched result).
 ``benchmarks/kernels_bench.py`` asserts the fused batch-256 get pipeline
-pays exactly one per batch.
+pays exactly one sync per batch.
 """
 from __future__ import annotations
 
@@ -40,22 +46,12 @@ from collections import OrderedDict
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import keys as CK
 from repro.kernels import ops
-
-# host↔device sync points (device→host result fetches); module-level so
-# benchmarks/tests can assert the "one sync per batch" contract
-SYNCS = 0
-
-
-def _fetch(*arrays):
-    """The single blocking device→host transfer of a fused batch."""
-    global SYNCS
-    SYNCS += 1
-    return jax.device_get(arrays)
+from repro.obs import tracing as _tracing
+from repro.obs.tracing import fetch
 
 
 def _pow2pad(n: int) -> int:
@@ -125,6 +121,8 @@ class DeviceViewManager:
             events = NULL_EVENTS
         self.events = events
         self._c_batches = registry.counter("device_batches")
+        self._c_launches = registry.counter("device_launches")
+        self._c_syncs = registry.counter("device_syncs")
         self._c_rows = registry.counter("device_rows_gathered")
         self._c_fallback = registry.counter("device_fallback_total")
         registry.gauge("hbm_resident_bytes", fn=lambda: self._resident)
@@ -200,28 +198,35 @@ class DeviceViewManager:
         """Batched point gets. Full tier: one fused device composition +
         one result fetch. Index tier: the same single round trip returns
         (found, run, row) and values come from the host block cache."""
-        keys_u64 = np.asarray(keys_u64, np.uint64)
-        q = len(keys_u64)
-        pad = _pow2pad(q)
-        kq = np.pad(keys_u64, (0, pad - q))
-        qk = jnp.asarray(CK.pack_u64(kq))
-        nw = jnp.uint32(int(now))
-        fd, vd, rid_d, row_d = ops.get_live(
-            dv.remix, dv.runset, dv.exp, qk, nw, interpret=self.interpret
-        )
-        self._c_batches.inc()
+        with _tracing.span("launch"):
+            keys_u64 = np.asarray(keys_u64, np.uint64)
+            q = len(keys_u64)
+            pad = _pow2pad(q)
+            kq = np.pad(keys_u64, (0, pad - q))
+            qk = jnp.asarray(CK.pack_u64(kq))
+            nw = jnp.uint32(int(now))
+            fd, vd, rid_d, row_d = ops.get_live(
+                dv.remix, dv.runset, dv.exp, qk, nw, interpret=self.interpret
+            )
+            self._c_launches.inc()
+            self._c_batches.inc()
         if dv.tier == "full":
-            found, vals = _fetch(fd, vd)  # THE one host sync
-            found, vals = found[:q], vals[:q]
-            self._c_rows.inc(int(found.sum()))
+            # THE one host sync
+            found, vals = fetch(self._c_syncs, fd, vd)
+            with _tracing.span("unpack"):
+                del qk, nw, fd, vd, rid_d, row_d  # frees the device buffers
+                found, vals = found[:q], vals[:q]
+                self._c_rows.inc(int(found.sum()))
             return found, vals
-        found, rid, row = _fetch(fd, rid_d, row_d)
-        found, rid, row = found[:q], rid[:q], row[:q]
-        vals = np.zeros((q, dv.vw), np.uint32)
-        for r in np.unique(rid[found]):
-            m = found & (rid == r)
-            vals[m] = dv.tables[r].rows_scattered("vals", row[m])
-        self._c_rows.inc(int(found.sum()))
+        found, rid, row = fetch(self._c_syncs, fd, rid_d, row_d)
+        with _tracing.span("unpack"):
+            del qk, nw, fd, vd, rid_d, row_d
+            found, rid, row = found[:q], rid[:q], row[:q]
+            vals = np.zeros((q, dv.vw), np.uint32)
+            for r in np.unique(rid[found]):
+                m = found & (rid == r)
+                vals[m] = dv.tables[r].rows_scattered("vals", row[m])
+            self._c_rows.inc(int(found.sum()))
         return found, vals
 
     def scan_windows(
@@ -233,21 +238,26 @@ class DeviceViewManager:
         window, same semantics as the host `gather_view` path."""
         starts_u64 = np.asarray(starts_u64, np.uint64)
         q = len(starts_u64)
-        nw = jnp.uint32(int(now))
-        if dv.tier == "full" or not with_vals:
+        if dv.tier != "full" and with_vals:
+            return self._scan_pipelined(dv, starts_u64, width, now)
+        with _tracing.span("launch"):
+            nw = jnp.uint32(int(now))
             pad = _pow2pad(q)
             sq = np.pad(starts_u64, (0, pad - q))
             qk = jnp.asarray(CK.pack_u64(sq))
-            kd, vd, md, _, _, _ = ops.scan_live(
+            kd, vd, md, *rest = ops.scan_live(
                 dv.remix, dv.runset, dv.exp, qk, nw, width=width,
                 interpret=self.interpret,
             )
+            self._c_launches.inc()
             self._c_batches.inc()
-            if with_vals:
-                keys, vals, valid = _fetch(kd, vd, md)
-            else:
-                keys, valid = _fetch(kd, md)
-                vals = None
+        if with_vals:
+            keys, vals, valid = fetch(self._c_syncs, kd, vd, md)
+        else:
+            keys, valid = fetch(self._c_syncs, kd, md)
+            vals = None
+        with _tracing.span("unpack"):
+            del qk, nw, kd, vd, md, rest  # frees the device buffers
             out = []
             rows = 0
             for i in range(q):
@@ -256,10 +266,9 @@ class DeviceViewManager:
                 rows += len(kk)
                 out.append((kk, vals[i][m] if with_vals else None))
             self._c_rows.inc(rows)
-            return out
-        return self._scan_pipelined(dv, starts_u64, width, nw)
+        return out
 
-    def _scan_pipelined(self, dv, starts_u64, width, nw) -> list:
+    def _scan_pipelined(self, dv, starts_u64, width, now) -> list:
         """Index tier: double-buffered batch-sliced pipeline. The device
         resolves row windows for slice i+1 (async dispatch) while the
         host gathers slice i's value granules through the BlockCache."""
@@ -269,14 +278,18 @@ class DeviceViewManager:
         padded = np.zeros(nsl * s, np.uint64)
         padded[:q] = starts_u64
         pad = _pow2pad(s)
+        nw = jnp.uint32(int(now))
 
         def launch(si):
-            sq = np.pad(padded[si * s:(si + 1) * s], (0, pad - s))
-            qk = jnp.asarray(CK.pack_u64(sq))
-            return ops.scan_live(
-                dv.remix, dv.runset, dv.exp, qk, nw, width=width,
-                interpret=self.interpret,
-            )
+            with _tracing.span("launch"):
+                sq = np.pad(padded[si * s:(si + 1) * s], (0, pad - s))
+                qk = jnp.asarray(CK.pack_u64(sq))
+                out = ops.scan_live(
+                    dv.remix, dv.runset, dv.exp, qk, nw, width=width,
+                    interpret=self.interpret,
+                )
+                self._c_launches.inc()
+            return out
 
         out: list = []
         rows = 0
@@ -284,25 +297,28 @@ class DeviceViewManager:
         for si in range(nsl):
             nxt = launch(si + 1) if si + 1 < nsl else None
             kd, _, md, rid_d, row_d, _ = pending
-            keys, valid, rid, row = _fetch(kd, md, rid_d, row_d)
+            keys, valid, rid, row = fetch(
+                self._c_syncs, kd, md, rid_d, row_d
+            )
             self._c_batches.inc()
-            nq = min(s, q - si * s)
-            keys, valid = keys[:nq], valid[:nq]
-            rid, row = rid[:nq], row[:nq]
-            # slice value gather: group live rows per run, one scattered
-            # (granule-deduped) fetch per touched table
-            vals = np.zeros((nq, width, dv.vw), np.uint32)
-            rid_f, row_f = rid[valid], row[valid]
-            gath = np.zeros((len(rid_f), dv.vw), np.uint32)
-            for r in np.unique(rid_f):
-                m = rid_f == r
-                gath[m] = dv.tables[r].rows_scattered("vals", row_f[m])
-            vals[valid] = gath
-            for i in range(nq):
-                m = valid[i]
-                kk = CK.unpack_u64(keys[i][m])
-                rows += len(kk)
-                out.append((kk, vals[i][m]))
+            with _tracing.span("unpack"):
+                nq = min(s, q - si * s)
+                keys, valid = keys[:nq], valid[:nq]
+                rid, row = rid[:nq], row[:nq]
+                # slice value gather: group live rows per run, one
+                # scattered (granule-deduped) fetch per touched table
+                vals = np.zeros((nq, width, dv.vw), np.uint32)
+                rid_f, row_f = rid[valid], row[valid]
+                gath = np.zeros((len(rid_f), dv.vw), np.uint32)
+                for r in np.unique(rid_f):
+                    m = rid_f == r
+                    gath[m] = dv.tables[r].rows_scattered("vals", row_f[m])
+                vals[valid] = gath
+                for i in range(nq):
+                    m = valid[i]
+                    kk = CK.unpack_u64(keys[i][m])
+                    rows += len(kk)
+                    out.append((kk, vals[i][m]))
             pending = nxt
         self._c_rows.inc(rows)
         return out

@@ -3,6 +3,11 @@
 The kernels cover the compute-dense parts (anchor compare-count, selector
 occurrence decode); XLA handles the HBM gathers between them (TPU gathers
 are XLA's job — fusing them into Pallas would fight the memory system).
+
+Each stage runs under a ``jax.named_scope`` — ``remix_seek`` (anchor
+search and in-group lower bound), ``remix_gather`` (selector decode and
+the run gathers of a view window) and ``liveness`` (the validity mask)
+— so every device op carries its stage in the profiler's op metadata.
 """
 from __future__ import annotations
 
@@ -36,21 +41,24 @@ def seek(
     remix: Remix, runset: RunSet, queries: jnp.ndarray, *, interpret: bool
 ) -> jnp.ndarray:
     """Kernel-backed lower-bound seek; same contract as core.query.seek."""
-    queries = jnp.asarray(queries, jnp.uint32)
-    d = remix.d
-    g = anchor_search(remix.anchors, queries, interpret=interpret)  # (Q,)
-    sels = remix.selectors.reshape(remix.g, d)[g]  # (Q, D)
-    runid, absidx, newest, pad = selector_decode(
-        sels, remix.cursors[g], r=remix.r, interpret=interpret
-    )
-    keys, _, _, _ = runset.gather(runid, absidx)
-    keys = jnp.where(pad[..., None], K.UINT32_MAX, keys)
-    ge = ~K.key_lt(keys, queries[:, None, :])  # (Q, D)
-    s = jnp.argmax(ge, axis=1).astype(jnp.int32)
-    s = jnp.where(jnp.any(ge, axis=1), s, d)
-    is_pad = jnp.take_along_axis(pad, jnp.clip(s, 0, d - 1)[:, None], axis=1)[:, 0]
-    s = jnp.where((s < d) & is_pad, d, s)
-    return jnp.minimum(g * d + s, remix.n_slots)
+    with jax.named_scope("remix_seek"):
+        queries = jnp.asarray(queries, jnp.uint32)
+        d = remix.d
+        g = anchor_search(remix.anchors, queries, interpret=interpret)
+        sels = remix.selectors.reshape(remix.g, d)[g]  # (Q, D)
+        runid, absidx, newest, pad = selector_decode(
+            sels, remix.cursors[g], r=remix.r, interpret=interpret
+        )
+        keys, _, _, _ = runset.gather(runid, absidx)
+        keys = jnp.where(pad[..., None], K.UINT32_MAX, keys)
+        ge = ~K.key_lt(keys, queries[:, None, :])  # (Q, D)
+        s = jnp.argmax(ge, axis=1).astype(jnp.int32)
+        s = jnp.where(jnp.any(ge, axis=1), s, d)
+        is_pad = jnp.take_along_axis(
+            pad, jnp.clip(s, 0, d - 1)[:, None], axis=1
+        )[:, 0]
+        s = jnp.where((s < d) & is_pad, d, s)
+        return jnp.minimum(g * d + s, remix.n_slots)
 
 
 @partial(jax.jit, static_argnames=("width", "interpret"))
@@ -66,30 +74,32 @@ def gather_view(
     d = remix.d
     q = pos.shape[0]
     ng = (width + d - 1) // d + 1
-    g0 = jnp.clip(pos // d, 0, remix.g - 1)
-    gs = g0[:, None] + jnp.arange(ng, dtype=jnp.int32)[None, :]
-    gsc = jnp.clip(gs, 0, remix.g - 1)
-    sels = remix.selectors.reshape(remix.g, d)[gsc].reshape(q * ng, d)
-    curs = remix.cursors[gsc].reshape(q * ng, remix.r)
-    runid, absidx, newest, pad = selector_decode(
-        sels, curs, r=remix.r, interpret=interpret
-    )
-    keys, vals, _, tomb = runset.gather(runid, absidx)
-    keys = jnp.where(pad[..., None], K.UINT32_MAX, keys)
+    with jax.named_scope("remix_gather"):
+        g0 = jnp.clip(pos // d, 0, remix.g - 1)
+        gs = g0[:, None] + jnp.arange(ng, dtype=jnp.int32)[None, :]
+        gsc = jnp.clip(gs, 0, remix.g - 1)
+        sels = remix.selectors.reshape(remix.g, d)[gsc].reshape(q * ng, d)
+        curs = remix.cursors[gsc].reshape(q * ng, remix.r)
+        runid, absidx, newest, pad = selector_decode(
+            sels, curs, r=remix.r, interpret=interpret
+        )
+        keys, vals, _, tomb = runset.gather(runid, absidx)
+        keys = jnp.where(pad[..., None], K.UINT32_MAX, keys)
 
-    def reshape_q(x):
-        return x.reshape((q, ng * d) + x.shape[2:])
+        def reshape_q(x):
+            return x.reshape((q, ng * d) + x.shape[2:])
 
-    off = pos - g0 * d
+        off = pos - g0 * d
 
-    def slice_one(x, o):
-        return jax.lax.dynamic_slice_in_dim(x, o, width, axis=0)
+        def slice_one(x, o):
+            return jax.lax.dynamic_slice_in_dim(x, o, width, axis=0)
 
-    take = lambda x: jax.vmap(slice_one)(reshape_q(x), off)
-    keys, vals = take(keys), take(vals)
-    newest, pad, tomb = take(newest), take(pad), take(tomb)
-    gslot = pos[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
-    valid = newest & ~pad & ~tomb & (gslot < remix.n_slots)
+        take = lambda x: jax.vmap(slice_one)(reshape_q(x), off)
+        keys, vals = take(keys), take(vals)
+        newest, pad, tomb = take(newest), take(pad), take(tomb)
+    with jax.named_scope("liveness"):
+        gslot = pos[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+        valid = newest & ~pad & ~tomb & (gslot < remix.n_slots)
     return keys, vals, valid
 
 
@@ -104,7 +114,8 @@ def get(remix, runset, queries, *, interpret: bool):
     queries = jnp.asarray(queries, jnp.uint32)
     pos = seek(remix, runset, queries, interpret=interpret)
     keys, vals, valid = gather_view(remix, runset, pos, 1, interpret=interpret)
-    found = valid[:, 0] & K.key_eq(keys[:, 0], queries)
+    with jax.named_scope("liveness"):
+        found = valid[:, 0] & K.key_eq(keys[:, 0], queries)
     return found, vals[:, 0]
 
 
@@ -135,22 +146,25 @@ def gather_view_live(
     d = remix.d
     q = pos.shape[0]
     ng = (width + d - 1) // d + 1
-    g0 = jnp.clip(pos // d, 0, remix.g - 1)
-    gs = g0[:, None] + jnp.arange(ng, dtype=jnp.int32)[None, :]
-    gsc = jnp.clip(gs, 0, remix.g - 1)
-    sels = remix.selectors.reshape(remix.g, d)[gsc].reshape(q * ng, d)
-    curs = remix.cursors[gsc].reshape(q * ng, remix.r)
-    runid, absidx, newest, pad = selector_decode(
-        sels, curs, r=remix.r, interpret=interpret
-    )
-    keys, vals, _, tomb = runset.gather(runid, absidx)
-    keys = jnp.where(pad[..., None], K.UINT32_MAX, keys)
-    # exp gather clips exactly like RunSet.gather so pad slots stay benign
-    ex = exp[
-        jnp.clip(runid, 0, exp.shape[0] - 1),
-        jnp.clip(absidx, 0, exp.shape[1] - 1),
-    ]
-    dead = tomb | ((ex != 0) & (ex <= now))
+    with jax.named_scope("remix_gather"):
+        g0 = jnp.clip(pos // d, 0, remix.g - 1)
+        gs = g0[:, None] + jnp.arange(ng, dtype=jnp.int32)[None, :]
+        gsc = jnp.clip(gs, 0, remix.g - 1)
+        sels = remix.selectors.reshape(remix.g, d)[gsc].reshape(q * ng, d)
+        curs = remix.cursors[gsc].reshape(q * ng, remix.r)
+        runid, absidx, newest, pad = selector_decode(
+            sels, curs, r=remix.r, interpret=interpret
+        )
+        keys, vals, _, tomb = runset.gather(runid, absidx)
+        keys = jnp.where(pad[..., None], K.UINT32_MAX, keys)
+    with jax.named_scope("liveness"):
+        # exp gather clips exactly like RunSet.gather so pad slots stay
+        # benign
+        ex = exp[
+            jnp.clip(runid, 0, exp.shape[0] - 1),
+            jnp.clip(absidx, 0, exp.shape[1] - 1),
+        ]
+        dead = tomb | ((ex != 0) & (ex <= now))
 
     def reshape_q(x):
         return x.reshape((q, ng * d) + x.shape[2:])
@@ -161,11 +175,13 @@ def gather_view_live(
         return jax.lax.dynamic_slice_in_dim(x, o, width, axis=0)
 
     take = lambda x: jax.vmap(slice_one)(reshape_q(x), off)
-    keys, vals = take(keys), take(vals)
-    newest, pad, dead = take(newest), take(pad), take(dead)
-    runid, absidx = take(runid), take(absidx)
-    gslot = pos[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
-    valid = newest & ~pad & ~dead & (gslot < remix.n_slots)
+    with jax.named_scope("remix_gather"):
+        keys, vals = take(keys), take(vals)
+        newest, pad, dead = take(newest), take(pad), take(dead)
+        runid, absidx = take(runid), take(absidx)
+    with jax.named_scope("liveness"):
+        gslot = pos[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+        valid = newest & ~pad & ~dead & (gslot < remix.n_slots)
     return keys, vals, valid, runid, absidx
 
 
@@ -190,5 +206,6 @@ def get_live(remix, runset, exp, queries, now, *, interpret: bool):
     keys, vals, valid, runid, absidx = gather_view_live(
         remix, runset, exp, pos, now, 1, interpret=interpret
     )
-    found = valid[:, 0] & K.key_eq(keys[:, 0], queries)
+    with jax.named_scope("liveness"):
+        found = valid[:, 0] & K.key_eq(keys[:, 0], queries)
     return found, vals[:, 0], runid[:, 0], absidx[:, 0]

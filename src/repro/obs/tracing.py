@@ -2,18 +2,29 @@
 
 A :class:`Trace` is one tree of :class:`Span` s for one submitted batch:
 ``admission`` (backpressure wait) → ``queue`` (async pickup delay) →
-``plan`` → per-stage/per-shard execution groups → leaf spans recorded at
-the physical layers (``cache_fetch`` in the block cache, ``disk_read`` in
-the SSTable reader, ``ckb_decode`` in the compressed-key-block reader).
+``plan`` → per-stage/per-shard execution groups → the store's read and
+write steps (``pin``, ``overlay_probe``, ``route``, ``cursor_seek``,
+``cursor_window``, ``wal_append``, ``wal_sync``, ``memtable_apply``) and
+the device boundary (``launch``, ``device_wait``, ``unpack``) → leaf
+spans recorded at the physical layers (``cache_fetch`` in the block
+cache, ``disk_read`` in the SSTable reader, ``ckb_decode`` in the
+compressed-key-block reader) → ``finish``.
 
 Activation is a **thread-local**: the executor activates the batch's
-trace around execution, and leaf sites ask :func:`current` — a single
-``getattr`` on a ``threading.local`` — so the untraced hot path pays one
-predictable branch, nothing else. Traces reach callers on
-``BatchResult.trace`` (``Batch(trace=True)`` opt-in, or the
-``trace_sample_rate`` knob sampling 1-in-N batches deterministically) and
-export as Chrome ``trace_event`` JSON loadable in ``chrome://tracing`` /
-Perfetto.
+trace around execution, and instrumented sites ask :func:`current` (or
+:func:`span`) — one attribute read on a ``threading.local`` — so the
+untraced hot path pays one predictable branch and constructs nothing.
+Traces reach callers on ``BatchResult.trace`` (``Batch(trace=True)``
+opt-in, or the ``trace_sample_rate`` knob sampling 1-in-N batches
+deterministically) and export as Chrome ``trace_event`` JSON loadable in
+``chrome://tracing`` / Perfetto, one row per recording thread.
+
+One clock with the device trace: every live span (:meth:`Trace.span`)
+also enters a profiler ``TraceMe`` (``jax.profiler.TraceAnnotation``)
+of the same name on the thread doing the work, so a ``jax.profiler``
+trace holds the program's spans beside the device's operations. Spans
+recorded after the fact (:meth:`Trace.leaf`, e.g. ``queue``, which no
+thread works in) are not in the profiler's trace.
 
 Coverage accounting: :meth:`Trace.leaf_coverage` is the fraction of the
 root span's wall time covered by at least one instrumented child span —
@@ -27,15 +38,30 @@ from __future__ import annotations
 import json
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
-_tls = threading.local()
+import numpy as np
+
+import jax
+from jax.profiler import TraceAnnotation as _Annotation
+
+
+class _Local(threading.local):
+    # a class default: a thread that never activated a trace reads None
+    # without raising (getattr with a default catches an AttributeError)
+    trace = None
+
+
+_tls = _Local()
 
 now = time.perf_counter
 
+# what an untraced site enters: shared, so the fast path builds nothing
+NULL_SPAN = nullcontext()
+
 
 class Span:
-    __slots__ = ("name", "t0", "t1", "args", "children")
+    __slots__ = ("name", "t0", "t1", "args", "children", "tid")
 
     def __init__(self, name: str, t0: float, args: dict | None = None):
         self.name = name
@@ -43,6 +69,7 @@ class Span:
         self.t1 = t0
         self.args = args or {}
         self.children: list[Span] = []
+        self.tid = threading.get_ident()  # the recording thread
 
     @property
     def duration(self) -> float:
@@ -74,15 +101,17 @@ class Trace:
     # ---- recording ----
     @contextmanager
     def span(self, name: str, **args):
-        sp = Span(name, now(), args)
-        parent = self._stack[-1]
-        parent.children.append(sp)
-        self._stack.append(sp)
-        try:
-            yield sp
-        finally:
-            sp.t1 = now()
-            self._stack.pop()
+        """A live span under the current parent, timed around the block
+        and mirrored as a profiler annotation of the same name."""
+        with _Annotation(name):
+            sp = Span(name, now(), args)
+            self._stack[-1].children.append(sp)
+            self._stack.append(sp)
+            try:
+                yield sp
+            finally:
+                sp.t1 = now()
+                self._stack.pop()
 
     def leaf(self, name: str, t0: float, t1: float, **args) -> Span:
         """Record an already-timed leaf span under the current parent."""
@@ -124,12 +153,16 @@ class Trace:
     # ---- export ----
     def to_chrome(self, pid: int = 1, tid: int = 1) -> dict:
         """Chrome ``trace_event`` JSON object format (``ph: "X"`` complete
-        events, microsecond timestamps relative to the root start)."""
+        events, microsecond timestamps relative to the root start). Each
+        recording thread gets its own row: ``tid``, ``tid + 1``, ... in
+        order of first appearance (the submitting thread first)."""
         base = self.root.t0
+        rows: dict[int, int] = {}
         events = []
         for s in self.spans():
             ev = dict(
-                name=s.name, ph="X", pid=pid, tid=tid,
+                name=s.name, ph="X", pid=pid,
+                tid=rows.setdefault(s.tid, tid + len(rows)),
                 ts=round((s.t0 - base) * 1e6, 3),
                 dur=round(s.duration * 1e6, 3),
             )
@@ -156,8 +189,28 @@ def _jsonable(v):
 
 def current() -> Trace | None:
     """The trace activated on this thread, or None (the untraced fast
-    path: one thread-local getattr)."""
-    return getattr(_tls, "trace", None)
+    path: one thread-local attribute read)."""
+    return _tls.trace
+
+
+def span(name: str):
+    """A live span of ``name`` under the thread's active trace, or the
+    shared no-op context when none is active."""
+    tr = _tls.trace
+    return NULL_SPAN if tr is None else tr.span(name)
+
+
+def fetch(syncs, *arrays) -> tuple:
+    """The device→host boundary of every read path: one blocking
+    transfer of the device ``arrays``, returned as a tuple of host
+    arrays, counted in the ``syncs`` counter and timed as a
+    ``device_wait`` span. One array is read with ``np.asarray``; several
+    with ``jax.device_get``, which starts every copy before it waits."""
+    with span("device_wait"):
+        syncs.inc()
+        if len(arrays) == 1:
+            return (np.asarray(arrays[0]),)
+        return jax.device_get(arrays)
 
 
 @contextmanager
@@ -167,7 +220,7 @@ def activate(trace: Trace | None):
     if trace is None:
         yield None
         return
-    prev = getattr(_tls, "trace", None)
+    prev = _tls.trace
     _tls.trace = trace
     try:
         yield trace

@@ -9,7 +9,6 @@ import dataclasses
 import numpy as np
 import pytest
 
-import repro.kernels.device_view as device_view
 from repro.db import clock
 from repro.db.compaction import CompactionConfig
 from repro.db.store import RemixDB, RemixDBConfig
@@ -157,9 +156,14 @@ def test_index_tier_pipeline_parity(tmp_path):
         # a 24-query scan at slice width 4 crosses multiple slices: the
         # pipeline pays one sync per slice, never one per query
         starts = np.sort(rng.choice(domain, 24, replace=False))
-        s0 = device_view.SYNCS
+        names = ("device_syncs", "cursor_seeks", "cursor_windows")
+        s0 = [dev.registry.counter(n).value for n in names]
         dev.scan_batch(starts, 9)
-        assert device_view.SYNCS - s0 < len(starts)
+        syncs, seeks, windows = (
+            dev.registry.counter(n).value - v for n, v in zip(names, s0)
+        )
+        # rows the cursor answers pay one fetch per seek, three per window
+        assert 0 < syncs - seeks - 3 * windows < len(starts)
     finally:
         clock.reset()
         dev.close(), host.close()

@@ -557,3 +557,274 @@ def test_scrub_counters_and_events(tmp_path):
             "quarantine_purged", "io_retry", "io_giveup"} <= names
     assert db2.scrub(full=True)["clean"]
     db2.close()
+
+
+# ----------------------------------------- tracing on the device read path
+def _device_store(tmp_path, n=300):
+    """A one-partition store served through its device view (the
+    kernels interpreted on the CPU), with an empty MemTable."""
+    from repro.db.store import RemixDB, RemixDBConfig
+
+    db = RemixDB.open(
+        str(tmp_path / "dev"),
+        RemixDBConfig(memtable_entries=1 << 30, device_path="on",
+                      cold_reads=False),
+    )
+    keys = _fill(db, n=n)
+    db.flush()
+    assert len(db.partitions) == 1 and not len(db.mem)
+    return db, keys
+
+
+def _children(span):
+    return [c.name for c in span.children]
+
+
+@pytest.mark.parametrize("kind", ["get", "scan"])
+def test_device_read_span_tree(tmp_path, kind):
+    from repro.db.ops import Batch
+
+    db, keys = _device_store(tmp_path)
+
+    def batch(i):
+        k = int(keys[(37 * i) % 200])
+        b = Batch(trace=True)
+        return b.get(k) if kind == "get" else b.scan(k, 20)
+
+    for i in range(3):  # compiles every shape the batches use
+        assert db.submit(batch(i)).result().ok
+    traces = []
+    for i in range(7):
+        res = db.submit(batch(i)).result()
+        assert res.ok
+        traces.append(res.trace)
+    for tr in traces:
+        assert tr.well_formed()
+        assert _children(tr.root) == [
+            "admission", "queue", "plan", "stage0:read", "finish"]
+        (read,) = tr.find("shard0:read")
+        if kind == "get":
+            assert _children(read) == [
+                "pin", "overlay_probe", "route", "launch", "device_wait",
+                "unpack"]
+        else:
+            assert _children(read) == [
+                "pin", "route", "cursor_seek", "cursor_window"]
+            seek, window = read.children[2:]
+            assert _children(seek) == [
+                "overlay_sort", "route", "route", "launch", "device_wait",
+                "unpack"]
+            assert _children(window) == [
+                "launch", "device_wait", "device_wait", "device_wait",
+                "unpack", "merge"]
+        # the submitting thread and the worker each get their own row
+        rows = {e["name"]: e["tid"] for e in tr.to_chrome()["traceEvents"]}
+        assert rows["batch"] == rows["admission"] == 1
+        assert rows["shard0:read"] == rows["finish"] == 2
+    # at most 10% of the read is unnamed, in the median request (one
+    # request may meet a collector pause or a preempted thread)
+    reads = [tr.find("shard0:read")[0] for tr in traces]
+    unnamed = sorted(r.self_time() / r.duration for r in reads)
+    assert unnamed[len(unnamed) // 2] <= 0.10, unnamed
+    db.close()
+
+
+def test_live_spans_on_the_profiler_clock(tmp_path):
+    """Every live span of a traced batch is a host event of the same
+    name in a ``jax.profiler`` trace, on the recording thread's line,
+    nested as in the Trace and as long within 10% or 50 us."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.db.ops import Batch
+
+    db, keys = _device_store(tmp_path)
+    b = lambda: Batch(trace=True).get(int(keys[5])).scan(int(keys[9]), 8)
+    assert db.submit(b()).result().ok
+    prof = str(tmp_path / "prof")
+    jax.profiler.start_trace(prof)
+    try:
+        res = db.submit(b()).result()
+    finally:
+        jax.profiler.stop_trace()
+    assert res.ok
+    db.close()
+    (path,) = glob.glob(f"{prof}/plugins/profile/*/*.xplane.pb")
+    (host,) = [p for p in ProfileData.from_file(path).planes
+               if p.name == "/host:CPU"]
+    live = [s for s in res.trace.spans()[1:] if s.name != "queue"]
+    names = {s.name for s in live}
+    events: dict[str, list] = {}
+    for li, line in enumerate(host.lines):
+        for e in line.events:
+            if e.name in names:
+                events.setdefault(e.name, []).append(
+                    (e.start_ns, e.duration_ns, li))
+    ev_of = {}
+    for name in names:
+        spans = sorted((s for s in live if s.name == name),
+                       key=lambda s: s.t0)
+        evs = sorted(events.get(name, []))
+        assert len(evs) == len(spans), name
+        ev_of.update({id(s): e for s, e in zip(spans, evs)})
+    for s in live:
+        t0, dur, line = ev_of[id(s)]
+        assert abs(dur / 1e9 - s.duration) <= max(0.1 * s.duration, 50e-6)
+        for c in s.children:
+            if id(c) in ev_of:
+                c0, cd, cl = ev_of[id(c)]
+                assert cl == line and t0 <= c0 and c0 + cd <= t0 + dur
+
+
+def test_untraced_batch_constructs_no_annotation(tmp_path, monkeypatch):
+    from repro.db.ops import Batch
+    from repro.obs import tracing
+
+    class Refused:
+        def __init__(self, *a, **kw):
+            raise AssertionError("annotation built on the untraced path")
+
+    db, keys = _device_store(tmp_path)
+    monkeypatch.setattr(tracing, "_Annotation", Refused)
+    for b in (Batch().get(int(keys[3])), Batch().scan(int(keys[4]), 9),
+              Batch().scan(int(keys[4]), 9).scan(int(keys[40]), 9),
+              Batch().put(11, [1, 2])):
+        res = db.submit(b).result()
+        assert res.ok and res.trace is None
+    with pytest.raises(AssertionError, match="untraced path"):
+        with Trace().span("x"):
+            pass
+    db.close()
+
+
+def test_device_boundary_counters(tmp_path):
+    from repro.db.ops import Batch
+
+    db, keys = _device_store(tmp_path)
+    names = ("device_launches", "device_syncs", "device_batches",
+             "cursor_seeks", "cursor_windows")
+    fb = {r: db.registry.counter("scan_cursor_fallbacks", reason=r)
+          for r in ("lone", "overlay", "underfull")}
+
+    def delta(b):
+        before = [db.registry.counter(n).value for n in names]
+        f0 = {r: c.value for r, c in fb.items()}
+        assert db.submit(b).result().ok
+        out = {n: db.registry.counter(n).value - v
+               for n, v in zip(names, before)}
+        out.update({r: c.value - f0[r] for r, c in fb.items()})
+        return {k: v for k, v in out.items() if v}
+
+    k = [int(x) for x in keys]
+    # a lone get: one fused launch, one sync
+    assert delta(Batch().get(k[3])) == {
+        "device_launches": 1, "device_syncs": 1, "device_batches": 1}
+    # a lone scan: the cursor's seek (one fetch) and one window (three)
+    assert delta(Batch().scan(k[3], 9)) == {
+        "device_launches": 2, "device_syncs": 4, "cursor_seeks": 1,
+        "cursor_windows": 1, "lone": 1}
+    # a batched scan: one fused window launch and its sync
+    assert delta(Batch().scan(k[3], 9).scan(k[50], 9)) == {
+        "device_launches": 1, "device_syncs": 1, "device_batches": 1}
+    # a window past the last key is under-full: the cursor answers it,
+    # widening its windows to the end of the view
+    d = delta(Batch().scan(k[3], 9).scan(k[-2], 9))
+    assert (d["underfull"], d["cursor_seeks"], d["device_batches"]) \
+        == (1, 1, 1)
+    assert d["device_launches"] == 2 + d["cursor_windows"]
+    assert d["device_syncs"] == 2 + 3 * d["cursor_windows"]
+    # a non-empty MemTable overlay: every scan of the group by cursor
+    db.put(5, [1, 2])
+    assert delta(Batch().scan(k[3], 9).scan(k[50], 9)) == {
+        "device_launches": 4, "device_syncs": 8, "cursor_seeks": 2,
+        "cursor_windows": 2, "overlay": 2}
+    db.close()
+
+
+def test_kernel_stages_in_lowered_get_live():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.remix import Remix
+    from repro.core.runs import RunSet
+    from repro.kernels import ops
+
+    d, r, n, g, vw, kw, q = 8, 2, 64, 16, 2, 2, 8
+    s = jax.ShapeDtypeStruct
+    remix = Remix(anchors=s((g, kw), jnp.uint32),
+                  cursors=s((g, r), jnp.int32),
+                  selectors=s((g * d,), jnp.uint8),
+                  n_entries=s((), jnp.int32), d=d)
+    runset = RunSet(keys=s((r, n, kw), jnp.uint32),
+                    vals=s((r, n, vw), jnp.uint32),
+                    seq=s((r, n), jnp.uint32), tomb=s((r, n), jnp.bool_),
+                    lens=s((r,), jnp.int32))
+    text = ops.get_live.lower(
+        remix, runset, s((r, n), jnp.uint32), s((q, kw), jnp.uint32),
+        s((), jnp.uint32), interpret=True,
+    ).as_text(debug_info=True)
+    for scope in ("remix_seek", "remix_gather", "liveness"):
+        assert scope in text, scope
+
+
+# -------------------------------- the benchmark's readers of these spans
+def _reader(name):
+    import importlib.util
+    import pathlib
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    spec = importlib.util.spec_from_file_location(
+        f"reader_{name}", root / "bench" / "metrics" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def _ctx(instrumented: bool):
+    """Three kept reads (one a MemTable hit with no device work), one
+    read the harness did not keep, and two inserts, one of which synced;
+    each span of ``t`` ms."""
+    from types import SimpleNamespace as NS
+
+    def done(is_write, spans):
+        tr = None
+        if spans is not None:
+            tr = Trace()
+            for name, ms in spans:
+                tr.leaf(name, 0.0, ms / 1e3)
+        return NS(req=NS(is_write=is_write),
+                  result=None if spans is None else NS(trace=tr))
+
+    if instrumented:
+        reads = [[("launch", 1.0), ("device_wait", 2.0)],
+                 [("launch", 0.5), ("launch", 0.5), ("device_wait", 1.0),
+                  ("device_wait", 3.0)],
+                 [("pin", 0.1)]]
+        writes = [[("wal_append", 0.2), ("wal_sync", 1.2)],
+                  [("wal_append", 0.1)]]
+        counters = {"device_syncs": 9}
+    else:
+        reads = [[("shard0:read", 2.0)]] * 3
+        writes = [[("shard0:commit", 0.3)]] * 2
+        counters = {"device_batches": 2}
+    done = ([done(False, s) for s in reads] + [done(False, None)]
+            + [done(True, s) for s in writes])
+    return NS(done=done, counters=counters)
+
+
+@pytest.mark.parametrize("name, want", [
+    ("launch_ms", 2.0 / 3),
+    ("device_wait_ms", 6.0 / 3),
+    ("host_syncs_per_read_op", 9 / 4),
+    ("wal_sync_ms", 1.2 / 2),
+])
+def test_span_metric_readers(name, want):
+    read = _reader(name)
+    assert read(_ctx(True)) == pytest.approx(want)
+    # a program without the spans and the counter reads nothing
+    assert read(_ctx(False)) is None
