@@ -9,8 +9,10 @@ stream of live ``(key, value)`` entries:
 - cold partitions (on-disk REMIX walk: one anchors search + bounded CKB
   seeks at ``seek``, then pure selector-stream decodes per window —
   :meth:`repro.db.partition.Partition.cold_cursor_window`),
-- promoted partitions (device REMIX: one jitted ``seek``, then
-  comparison-free ``gather_view`` windows from the saved position).
+- promoted partitions (device REMIX: the seek fused with the first
+  window in one jitted call, then comparison-free ``gather_view``
+  windows from the saved position; each window comes back as one
+  device buffer, :func:`window_buffer`).
 
 The defining property vs repeated ``scan(start, n)`` calls: a cursor
 seeks **once**. ``next``/``next_batch`` advance a persisted view
@@ -22,19 +24,26 @@ consumers. Because the snapshot pins its Version, iteration is immune to
 concurrent flushes: a compaction publishing a new Version never changes
 what an open cursor returns.
 
-Under an active trace each stream open is a ``cursor_seek`` span
-(``overlay_sort`` on the first, ``route``, then the device seek's
-``launch``, ``device_wait`` and ``unpack``) and each window pull a
-``cursor_window`` span (``launch``, one ``device_wait`` per fetched
-array, ``unpack``, ``merge``); the device calls count in the store's
-``cursor_seeks``, ``cursor_windows``, ``device_launches`` and
-``device_syncs``.
+On a promoted partition every window is one launch and one fetch: a
+lone Seek+NextN that fits its first window pays a single device round
+trip. Under an active trace each stream open is a ``cursor_seek`` span
+(``overlay_sort`` on the first, ``route``, then the fused seek and first
+window's ``launch``, ``device_wait`` and ``unpack``) and each window
+pull a ``cursor_window`` span (for a later device window ``launch``,
+one ``device_wait`` and ``unpack``; then ``merge``). The device calls
+count in the store's ``cursor_seeks`` (stream opens on a promoted
+partition), ``cursor_windows`` (every device window, the fused first
+one included), ``device_launches`` and ``device_syncs``.
 """
 from __future__ import annotations
 
 import bisect
+from functools import partial
 
 import numpy as np
+
+import jax
+import jax.numpy as jnp
 
 from repro.core import keys as CK
 from repro.db import clock
@@ -43,6 +52,37 @@ from repro.db.sharded import partition_spans, route_one
 from repro.obs import tracing as _tracing
 
 _MAX_WIDTH = 4096  # widening cap over tombstone/old-version runs
+
+
+@partial(jax.jit, static_argnames=("op", "width", "opts"))
+def window_buffer(remix, runset, at, *, op, width: int, opts: tuple = ()):
+    """One cursor window as one device buffer, in one program.
+
+    ``op`` is a query module's ``scan`` (``at``: start keys (Q, KW); the
+    seek fused with the first window) or its ``gather_view`` (``at``:
+    saved view positions (Q,) int32); ``opts`` are its keyword options
+    as ``(name, value)`` pairs. The outputs are packed into one flat
+    uint32 array — key words, value words, validity as 0/1, then the
+    view positions the window starts at — so the host makes one fetch
+    (:func:`unpack_window`) where it would make one per output."""
+    out = op(remix, runset, at, width=width, **dict(opts))
+    keys, vals, valid = out[:3]
+    pos = out[3] if len(out) > 3 else at
+    u32 = jnp.uint32
+    return jnp.concatenate([
+        keys.reshape(-1), vals.reshape(-1),
+        valid.reshape(-1).astype(u32), pos.reshape(-1).astype(u32),
+    ])
+
+
+def unpack_window(buf: np.ndarray, width: int, kw: int, vw: int):
+    """One query's :func:`window_buffer`, fetched: views ``keys (W, KW)``,
+    ``vals (W, VW)``, ``valid (W,)`` bool and the start position."""
+    nk, nv = width * kw, width * vw
+    keys = buf[:nk].reshape(width, kw)
+    vals = buf[nk:nk + nv].reshape(width, vw)
+    valid = buf[nk + nv:nk + nv + width].astype(bool)
+    return keys, vals, valid, int(buf[nk + nv + width])
 
 
 class RemixCursor:
@@ -67,6 +107,7 @@ class RemixCursor:
         self._buffered = 0
         self._done = True
         self._stream = None
+        self._pulled = None  # a stream's first window, fused with its open
 
     # ---------------- positioning ----------------
     def seek(self, key: int) -> "RemixCursor":
@@ -77,6 +118,7 @@ class RemixCursor:
         self._okeys = None
         self._first = True
         self._stream = None
+        self._pulled = None
         self._width = self.base_width
         self._chunks = []
         self._buffered = 0
@@ -190,8 +232,9 @@ class RemixCursor:
 
     def _open_stream(self):
         """Start the table-entry stream of the current partition: one
-        seek (cold: anchors + bounded CKB; promoted: jitted device seek),
-        after which every window is a pure position advance."""
+        seek (cold: anchors + bounded CKB; promoted: the device seek,
+        fused with the first window), after which every window is a pure
+        position advance."""
         with _tracing.span("route"):
             p = self.snap.partitions[self._pi]
             lo, _ = self._spans[self._pi]
@@ -202,20 +245,47 @@ class RemixCursor:
         if cold:
             self._stream = ("cold", p, p.cold_cursor_seek(start))
             return
-        import jax.numpy as jnp
+        self._stream = ["dev", p, None, None, None]
+        self._pulled = self._device_window(start)
 
-        store = self.store
+    def _device_window(self, start: int | None = None):
+        """One window of the promoted stream: one launch and one fetch.
+        A stream with no position yet seeks ``start`` in the same call.
+        Returns (keys u64, vals, partition_done)."""
+        stream, store = self._stream, self.store
+        _, p, remix, runset, pos = stream
+        mod, kw = store._query_mod(), store._qkw()
         with _tracing.span("launch"):
-            remix, runset = p.index()
-            qk = jnp.asarray(CK.pack_u64(np.array([start], np.uint64)))
-            pos_d = store._query_mod().seek(remix, runset, qk,
-                                            **store._qkw())
-            store._c_cursor_seeks.inc()
+            if pos is None:
+                remix, runset = p.index()
+                stream[2:4] = remix, runset
+                op = mod.scan
+                at = CK.pack_u64(np.array([start], np.uint64))
+                store._c_cursor_seeks.inc()
+            else:
+                op = mod.gather_view
+                at = np.array([pos], np.int32)
+                if not store.cfg.use_kernels:
+                    kw = {}  # the reference gather has no in-group search
+            # whole groups: the decode covers ceil(width / d) + 1 groups
+            # whatever the width, so this adds no device work, and one
+            # program per group count (not per width) keeps the fused
+            # seek's compiles few
+            width = -(-self._width // remix.d) * remix.d
+            buf_d = window_buffer(remix, runset, at, op=op, width=width,
+                                  opts=tuple(kw.items()))
+            store._c_cursor_windows.inc()
             store._c_launches.inc()
-        (pos,) = _tracing.fetch(store._c_syncs, pos_d)
+        (buf,) = _tracing.fetch(store._c_syncs, buf_d)
         with _tracing.span("unpack"):
-            del qk, pos_d  # frees the device buffers
-            self._stream = ["dev", p, remix, runset, int(pos[0])]
+            del buf_d  # frees the device buffer
+            keys, vals, valid, pos = unpack_window(
+                buf, width, runset.keys.shape[-1], runset.vals.shape[-1],
+            )
+            kk, vv, clipped = self._clip(CK.unpack_u64(keys[valid]),
+                                         vals[valid])
+            stream[4] = pos + width
+        return kk, vv, clipped or stream[4] >= remix.n_slots
 
     def _next_window(self):
         """One window of live table entries from the current partition.
@@ -227,31 +297,11 @@ class RemixCursor:
                 prefetch_depth=self.store.cfg.prefetch_depth,
             )
             kk, vv, clipped = self._clip(kk, vv)
+            done = clipped or not more
+        elif self._pulled is not None:
+            (kk, vv, done), self._pulled = self._pulled, None
         else:
-            _, p, remix, runset, pos = self._stream
-            import jax.numpy as jnp
-
-            store = self.store
-            with _tracing.span("launch"):
-                kw = store._qkw() if store.cfg.use_kernels else {}
-                keys_d, vals_d, valid_d = store._query_mod().gather_view(
-                    remix, runset, jnp.asarray([pos], jnp.int32),
-                    self._width, **kw,
-                )
-                store._c_cursor_windows.inc()
-                store._c_launches.inc()
-            # one blocking fetch per array, in the order they are read
-            (valid,) = _tracing.fetch(store._c_syncs, valid_d)
-            (keys,) = _tracing.fetch(store._c_syncs, keys_d)
-            (vals,) = _tracing.fetch(store._c_syncs, vals_d)
-            with _tracing.span("unpack"):
-                del keys_d, vals_d, valid_d  # frees the device buffers
-                v0 = valid[0]
-                kk, vv, clipped = self._clip(
-                    CK.unpack_u64(keys[0][v0]), vals[0][v0]
-                )
-            more = pos + self._width < remix.n_slots
-            self._stream[4] = pos + self._width
+            kk, vv, done = self._device_window()
         # adaptive widening, two cases sharing one rule: an all-invalid
         # window (tombstone/old-version run) must grow so long dead runs
         # cost O(log) decodes, and a productive stream grows as read-ahead
@@ -260,7 +310,7 @@ class RemixCursor:
         # decodes. Re-seeking scans can't do this: read-ahead is only
         # free when the position survives the call.
         self._width = min(self._width * 2, _MAX_WIDTH)
-        return kk, vv, clipped or not more
+        return kk, vv, done
 
     def _clip(self, kk, vv):
         """A window's entries inside the partition's key range and

@@ -897,16 +897,17 @@ class Executor:
 
     def _exec_scans(self, fut, batch, deadlines, results, g, view):
         for with_vals, idxs in g.scans.items():
-            live = self._precheck(fut, deadlines, results, idxs)
-            if not live:
-                continue
-            starts = np.array(
-                [batch.ops[i].start for i in live], np.uint64
-            )
-            ns = np.array([batch.ops[i].n for i in live], np.int64)
-            checks = [
-                self._interrupt_for(fut, deadlines[i]) for i in live
-            ]
+            with _tracing.span("scan_args"):
+                live = self._precheck(fut, deadlines, results, idxs)
+                if not live:
+                    continue
+                starts = np.array(
+                    [batch.ops[i].start for i in live], np.uint64
+                )
+                ns = np.array([batch.ops[i].n for i in live], np.int64)
+                checks = [
+                    self._interrupt_for(fut, deadlines[i]) for i in live
+                ]
             try:
                 rows = self.stores[g.shard]._scan_group_at(
                     view(g.shard), starts, ns,
@@ -935,27 +936,28 @@ class Executor:
                     results[i] = OpResult(status=OpStatus.ERROR,
                                           error=repr(e), exc=e)
                 continue
-            for i, row in zip(live, rows):
-                if row is None:  # failed in the isolation fallback
-                    continue
-                if isinstance(row, OpInterrupted):
-                    results[i] = OpResult(status=row.status)
-                    continue
-                kk, vv = row
-                kk, vv = self._clip_to_span(g.shard, kk, vv)
-                try:
-                    kk, vv = self._drain_scan(
-                        fut, deadlines[i], g.shard, kk, vv,
-                        batch.ops[i].n, with_vals, view,
-                    )
-                except OpInterrupted as e:
-                    results[i] = OpResult(status=e.status)
-                    continue
-                except Exception as e:
-                    results[i] = OpResult(status=_status_for(e),
-                                          error=repr(e), exc=e)
-                    continue
-                results[i] = OpResult(status=OpStatus.OK, keys=kk, vals=vv)
+            with _tracing.span("scan_results"):
+                for i, row in zip(live, rows):
+                    if row is None:  # failed in the isolation fallback
+                        continue
+                    if isinstance(row, OpInterrupted):
+                        results[i] = OpResult(status=row.status)
+                        continue
+                    kk, vv = row
+                    kk, vv = self._clip_to_span(g.shard, kk, vv)
+                    try:
+                        kk, vv = self._drain_scan(
+                            fut, deadlines[i], g.shard, kk, vv,
+                            batch.ops[i].n, with_vals, view,
+                        )
+                    except OpInterrupted as e:
+                        results[i] = OpResult(status=e.status)
+                        continue
+                    except Exception as e:
+                        results[i] = OpResult(status=_status_for(e),
+                                              error=repr(e), exc=e)
+                        continue
+                    results[i] = OpResult(status=OpStatus.OK, keys=kk, vals=vv)
 
     def _clip_to_span(self, shard: int, kk, vv):
         """Drop scan rows past the shard's owned [lo, hi) span. Rows are

@@ -1686,10 +1686,11 @@ class RemixDB:
                  interrupt=None):
         if self._unavailable:
             self._check_unavailable_scan(int(start_key))
-        cur = RemixCursor(view, width=max(8, n + n // 2),
-                          interrupt=interrupt)
-        cur.seek(int(start_key))
-        return cur.next_batch(n)
+        with _tracing.span("cursor"):
+            cur = RemixCursor(view, width=max(8, n + n // 2),
+                              interrupt=interrupt)
+            cur.seek(int(start_key))
+            return cur.next_batch(n)
 
     def scan_batch(self, starts, n: int):
         """Batched range scans (one jitted call per touched partition).

@@ -2,9 +2,10 @@
 
 A :class:`Trace` is one tree of :class:`Span` s for one submitted batch:
 ``admission`` (backpressure wait) → ``queue`` (async pickup delay) →
-``plan`` → per-stage/per-shard execution groups → the store's read and
-write steps (``pin``, ``overlay_probe``, ``route``, ``cursor_seek``,
-``cursor_window``, ``wal_append``, ``wal_sync``, ``memtable_apply``) and
+``plan`` → per-stage/per-shard execution groups (a scan group's
+``scan_args`` and ``scan_results``) → the store's read and write steps
+(``pin``, ``overlay_probe``, ``route``, ``cursor`` over ``cursor_seek``
+and ``cursor_window``, ``wal_append``, ``wal_sync``, ``memtable_apply``) and
 the device boundary (``launch``, ``device_wait``, ``unpack``) → leaf
 spans recorded at the physical layers (``cache_fetch`` in the block
 cache, ``disk_read`` in the SSTable reader, ``ckb_decode`` in the
@@ -88,6 +89,31 @@ class Span:
                f"{len(self.children)} children)"
 
 
+class _LiveSpan:
+    """The context of one :meth:`Trace.span`: a plain class, not a
+    generator context manager, which costs twice as much per span."""
+
+    __slots__ = ("trace", "name", "args", "ann", "sp")
+
+    def __init__(self, trace: "Trace", name: str, args: dict):
+        self.trace, self.name, self.args = trace, name, args
+
+    def __enter__(self) -> Span:
+        self.ann = _Annotation(self.name)
+        self.ann.__enter__()
+        sp = self.sp = Span(self.name, now(), self.args)
+        stack = self.trace._stack
+        stack[-1].children.append(sp)
+        stack.append(sp)
+        return sp
+
+    def __exit__(self, *exc) -> bool:
+        self.sp.t1 = now()
+        self.trace._stack.pop()
+        self.ann.__exit__(*exc)
+        return False
+
+
 class Trace:
     """One span tree. Not thread-safe across concurrent writers — the
     executor runs one batch's stages on one thread, which is the only
@@ -99,19 +125,10 @@ class Trace:
         self.sampled = False  # set when chosen by trace_sample_rate
 
     # ---- recording ----
-    @contextmanager
-    def span(self, name: str, **args):
+    def span(self, name: str, **args) -> "_LiveSpan":
         """A live span under the current parent, timed around the block
         and mirrored as a profiler annotation of the same name."""
-        with _Annotation(name):
-            sp = Span(name, now(), args)
-            self._stack[-1].children.append(sp)
-            self._stack.append(sp)
-            try:
-                yield sp
-            finally:
-                sp.t1 = now()
-                self._stack.pop()
+        return _LiveSpan(self, name, args)
 
     def leaf(self, name: str, t0: float, t1: float, **args) -> Span:
         """Record an already-timed leaf span under the current parent."""

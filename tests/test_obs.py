@@ -609,14 +609,16 @@ def test_device_read_span_tree(tmp_path, kind):
                 "unpack"]
         else:
             assert _children(read) == [
-                "pin", "route", "cursor_seek", "cursor_window"]
-            seek, window = read.children[2:]
+                "scan_args", "pin", "route", "cursor", "scan_results"]
+            (cursor,) = tr.find("cursor")
+            assert _children(cursor) == ["cursor_seek", "cursor_window"]
+            seek, window = cursor.children
             assert _children(seek) == [
                 "overlay_sort", "route", "route", "launch", "device_wait",
                 "unpack"]
-            assert _children(window) == [
-                "launch", "device_wait", "device_wait", "device_wait",
-                "unpack", "merge"]
+            # the seek and the first window are one call and one fetch:
+            # the window pulled at open only merges
+            assert _children(window) == ["merge"]
         # the submitting thread and the worker each get their own row
         rows = {e["name"]: e["tid"] for e in tr.to_chrome()["traceEvents"]}
         assert rows["batch"] == rows["admission"] == 1
@@ -721,9 +723,10 @@ def test_device_boundary_counters(tmp_path):
     # a lone get: one fused launch, one sync
     assert delta(Batch().get(k[3])) == {
         "device_launches": 1, "device_syncs": 1, "device_batches": 1}
-    # a lone scan: the cursor's seek (one fetch) and one window (three)
+    # a lone scan: the cursor's seek fused with its one window, one
+    # launch and one fetch
     assert delta(Batch().scan(k[3], 9)) == {
-        "device_launches": 2, "device_syncs": 4, "cursor_seeks": 1,
+        "device_launches": 1, "device_syncs": 1, "cursor_seeks": 1,
         "cursor_windows": 1, "lone": 1}
     # a batched scan: one fused window launch and its sync
     assert delta(Batch().scan(k[3], 9).scan(k[50], 9)) == {
@@ -733,12 +736,12 @@ def test_device_boundary_counters(tmp_path):
     d = delta(Batch().scan(k[3], 9).scan(k[-2], 9))
     assert (d["underfull"], d["cursor_seeks"], d["device_batches"]) \
         == (1, 1, 1)
-    assert d["device_launches"] == 2 + d["cursor_windows"]
-    assert d["device_syncs"] == 2 + 3 * d["cursor_windows"]
+    assert d["device_launches"] == 1 + d["cursor_windows"]
+    assert d["device_syncs"] == 1 + d["cursor_windows"]
     # a non-empty MemTable overlay: every scan of the group by cursor
     db.put(5, [1, 2])
     assert delta(Batch().scan(k[3], 9).scan(k[50], 9)) == {
-        "device_launches": 4, "device_syncs": 8, "cursor_seeks": 2,
+        "device_launches": 2, "device_syncs": 2, "cursor_seeks": 2,
         "cursor_windows": 2, "overlay": 2}
     db.close()
 

@@ -5,7 +5,12 @@ the fused ``ops.get_live`` / ``ops.scan_live`` compositions) are lowered
 through Mosaic and compiled for a described, not attached, v5e chip at
 the shapes ``chip_smoke.py`` drives: batch-256 gets, batch-64 scans of
 width 75 (Seek+Next50 plus the store's window slack), group size 32,
-16 padded runs of 65536 rows and 32768 groups per partition. Nothing
+16 padded runs of 65536 rows and 32768 groups per partition. The
+cursor's packed windows (``db.cursor.window_buffer``: the seek fused
+with the first window, and a later window from a saved position) are
+compiled for one query at widths 75 and 150, over both query modules
+(the kernels, and ``core.query``, which a store without
+``use_kernels`` runs on the chip). Nothing
 runs; the compiler refuses what the chip would refuse (VMEM overruns,
 unsupported primitives, misaligned tiles), which interpret mode cannot
 show. The topology is described inside a fixture so that only the test
@@ -19,8 +24,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import query as Q
 from repro.core.remix import Remix
 from repro.core.runs import RunSet
+from repro.db.cursor import window_buffer
 from repro.kernels import ops
 from repro.kernels.anchor_search import anchor_search
 from repro.kernels.selector_decode import selector_decode
@@ -51,9 +58,9 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile_has_kernel(fn, *args):
+def _compile_has_kernel(fn, *args, kernel: bool = True):
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert ("tpu_custom_call" in text) == kernel
 
 
 def _sds(sharding, shape, dtype):
@@ -103,4 +110,25 @@ def test_fused_get_and_scan_compile(one_chip):
             rm, rs, e, q, t, width=SCAN_WIDTH, interpret=False
         ),
         remix, runset, exp, _sds(one_chip, (Q_SCAN, KW), jnp.uint32), now,
+    )
+
+
+@pytest.mark.parametrize("width", [SCAN_WIDTH, 2 * SCAN_WIDTH])
+@pytest.mark.parametrize("seek", [True, False], ids=["fused_open", "window"])
+@pytest.mark.parametrize("kernels", [True, False],
+                         ids=["kernels", "core_query"])
+def test_cursor_window_buffer_compiles(one_chip, width, seek, kernels):
+    remix, runset, _, _ = _view(one_chip)
+    if kernels:
+        mod, opts = ops, (("interpret", False),)
+    else:
+        mod, opts = Q, (("ingroup", "vector"),) if seek else ()
+    if seek:
+        op, at = mod.scan, _sds(one_chip, (1, KW), jnp.uint32)
+    else:
+        op, at = mod.gather_view, _sds(one_chip, (1,), jnp.int32)
+    _compile_has_kernel(
+        lambda rm, rs, a: window_buffer(rm, rs, a, op=op, width=width,
+                                        opts=opts),
+        remix, runset, at, kernel=kernels,
     )
