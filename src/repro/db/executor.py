@@ -35,6 +35,16 @@ Async submission runs on a small worker pool (daemon threads, started
 lazily); ``submit(batch, sync=True)`` executes inline on the caller
 thread and returns an already-completed future — the mode the legacy
 wrapper methods use, so scalar ``put``/``get`` pay no thread hop.
+
+Coalescing: a worker that pops a read-only batch (one with no write
+tickets) while more jobs are queued also takes the read-only batches
+queued right behind it, up to ``GROUP_OPS`` ops, stopping at the first
+batch that writes or was cancelled, and executes them as one plan: one
+pinned view per shard, taken after the group formed, so every write
+acknowledged before any member was submitted is visible; one
+``_get_batch_at`` and one ``_scan_group_at`` per shard. Each member
+keeps its own results, statuses, deadlines, cancel flag and admission
+bytes. A worker that finds nothing else queued runs its batch alone.
 """
 from __future__ import annotations
 
@@ -72,6 +82,11 @@ log = logging.getLogger(__name__)
 # touching op IO_ERROR and trigger per-op isolation within a vectorized
 # group, instead of the generic whole-group ERROR
 _IO_ERRORS = (CorruptionError, TransientIOError, UnavailableSpanError)
+
+
+# most ops a worker executes as one coalesced group of queued read-only
+# batches (one device call per shard and read kind serves them all)
+GROUP_OPS = 64
 
 
 def _status_for(e: BaseException) -> OpStatus:
@@ -340,6 +355,13 @@ class Executor:
         reg.gauge("engine_queue_depth", fn=lambda: len(self._queue))
         reg.gauge("engine_workers", fn=lambda: len(self._threads))
         self._c_ordered = reg.counter("engine_ordered_batches")
+        # coalesced read groups, counted in the registry of each shard
+        # the group read (a single-shard engine's count is the group count)
+        self._c_coalesced = [
+            (r.counter("coalesced_groups"), r.counter("coalesced_requests"))
+            for r in (getattr(db, "registry", None) or reg
+                      for db in self.stores)
+        ]
         self._sampler = _tracing.Sampler(trace_sample_rate)
         self._c_traced = reg.counter("engine_batches_traced")
         self.last_trace: "_tracing.Trace | None" = None
@@ -479,43 +501,122 @@ class Executor:
                 if not self._queue:
                     return  # closed + drained
                 job = self._queue.pop(0)
+                t_take = _tracing.now()
+                jobs = self._take_group(job) if self._queue else [job]
+            if len(jobs) > 1:
+                self._run_group(jobs, t_take)
+                continue
             (fut, batch, deadlines, results, cost, wait_s,
              trace, t_enq, t_sub) = job
             if trace is not None:
-                trace.leaf("queue", t_enq, _tracing.now())
-            if not fut.set_running_or_notify_cancel():
-                # cancelled while queued: give the bytes back, count ops
-                self.admission.release(cost)
-                self._release_order(fut)
-                self._c_cancelled_batches.inc()
+                trace.leaf("queue", t_enq, t_take)
+            if not self._start(fut, cost):
                 continue
             self._run(fut, batch, deadlines, results, cost, wait_s,
                       trace=trace, t_sub=t_sub, mark_running=False)
 
+    def _start(self, fut, cost) -> bool:
+        """Mark a popped job running; False (its bytes and turn given
+        back) when it was cancelled while queued."""
+        if fut.set_running_or_notify_cancel():
+            return True
+        self.admission.release(cost)
+        self._release_order(fut)
+        self._c_cancelled_batches.inc()
+        return False
+
+    def _take_group(self, job) -> list:
+        """``job`` and the read-only, uncancelled jobs queued right behind
+        it, up to ``GROUP_OPS`` ops (call under ``_qcv``). A job with
+        write tickets ends the group, which keeps every write batch's
+        place in the sequencer's order."""
+
+        def combinable(j) -> bool:
+            return (j[0]._tickets is None and not j[0].cancelled()
+                    and all(op.is_read for op in j[1].ops))
+
+        jobs = [job]
+        if not combinable(job):
+            return jobs
+        n = len(job[1].ops)
+        while self._queue and combinable(self._queue[0]):
+            k = len(self._queue[0][1].ops)
+            if n + k > GROUP_OPS:
+                break
+            jobs.append(self._queue.pop(0))
+            n += k
+        return jobs
+
+    def _run_group(self, jobs, t_take) -> None:
+        """Execute coalesced read-only jobs as one plan, then finish each
+        member with its own slice of the results. The group's spans are
+        recorded once, on a trace of its own, and grafted onto every
+        traced member's tree (each member waited for all of them)."""
+        members = []
+        for job in jobs:
+            trace, t_enq = job[6], job[7]
+            if trace is not None:
+                trace.leaf("queue", t_enq, t_take)
+            if self._start(job[0], job[4]):
+                members.append(job)
+        if len(members) < 2:
+            for (fut, batch, deadlines, results, cost, wait_s,
+                 trace, _, t_sub) in members:
+                self._run(fut, batch, deadlines, results, cost, wait_s,
+                          trace=trace, t_sub=t_sub, mark_running=False)
+            return
+        ops, deadlines, results, flags, owners = [], [], [], [], []
+        for m, (fut, batch, dls, res, *_rest) in enumerate(members):
+            ops += batch.ops
+            deadlines += dls
+            results += res
+            flags += [fut.interrupted] * len(batch.ops)
+            owners += [m] * len(batch.ops)
+        traced = [job[6] for job in members if job[6] is not None]
+        gtrace = _tracing.Trace("group") if traced else None
+        t_formed = _tracing.now()
+        for tr in traced:
+            tr.leaf("coalesce", t_take, t_formed, requests=len(members),
+                    ops=len(ops))
+        batch = Batch(ops)
+        try:
+            with _tracing.activate(gtrace):
+                self._execute(None, batch, deadlines, results, gtrace,
+                              flags=flags, owners=owners)
+        except BaseException as e:  # plan-level failure: fail leftover ops
+            self._fail_leftovers(batch, results, e)
+        if gtrace is not None:
+            for tr in traced:
+                tr.root.children.extend(gtrace.root.children)
+        off = 0
+        for (fut, batch, _, res, cost, wait_s, trace, _, t_sub) in members:
+            res[:] = results[off:off + len(res)]
+            off += len(res)
+            self._finish(fut, batch, res, cost, wait_s, started=True,
+                         trace=trace, t_sub=t_sub)
+
     def _run(self, fut, batch, deadlines, results, cost, wait_s,
              trace=None, t_sub=None, mark_running=True) -> None:
-        if mark_running and not fut.set_running_or_notify_cancel():
-            self.admission.release(cost)
-            self._release_order(fut)
-            self._c_cancelled_batches.inc()
+        if mark_running and not self._start(fut, cost):
             return
         try:
             with _tracing.activate(trace):
                 self._execute(fut, batch, deadlines, results, trace)
         except BaseException as e:  # plan-level failure: fail leftover ops
-            for i, r in enumerate(results):
-                if r is None:
-                    results[i] = OpResult(status=OpStatus.ERROR,
-                                          error=repr(e), exc=e)
-            # structured failure path: a background batch failure lands
-            # in the event log + logging, not on a worker's stderr
-            self._c_batch_failures.inc()
-            self.events.emit("batch_error", error=repr(e),
-                             ops=len(batch.ops))
-            log.exception("op batch execution failed (%d ops)",
-                          len(batch.ops))
+            self._fail_leftovers(batch, results, e)
         self._finish(fut, batch, results, cost, wait_s, started=True,
                      trace=trace, t_sub=t_sub)
+
+    def _fail_leftovers(self, batch, results, e: BaseException) -> None:
+        for i, r in enumerate(results):
+            if r is None:
+                results[i] = OpResult(status=OpStatus.ERROR,
+                                      error=repr(e), exc=e)
+        # structured failure path: a background batch failure lands
+        # in the event log + logging, not on a worker's stderr
+        self._c_batch_failures.inc()
+        self.events.emit("batch_error", error=repr(e), ops=len(batch.ops))
+        log.exception("op batch execution failed (%d ops)", len(batch.ops))
 
     def _finish(self, fut, batch, results, cost, wait_s, started,
                 trace=None, t_sub=None) -> None:
@@ -609,9 +710,17 @@ class Executor:
         return int(route_host(self.lows, np.array([key], np.uint64))[0])
 
     # ---------------- execution ----------------
-    def _execute(self, fut, batch, deadlines, results, trace=None) -> None:
+    def _execute(self, fut, batch, deadlines, results, trace=None,
+                 flags=None, owners=None) -> None:
+        """Plan and run ``batch``. ``flags`` holds each op's cancel
+        event (all the batch future's by default); a coalesced group
+        passes its members' and ``owners``, each op's member number."""
+        if flags is None:
+            flags = [fut.interrupted] * len(batch.ops)
         with _span(trace, "plan"):
             stages = self.plan(batch)
+        if owners is not None:
+            self._count_coalesced(stages, owners)
         for idx, stage in enumerate(stages):
             with _span(trace, f"stage{idx}:{stage.kind}",
                        ops=len(stage.ops)):
@@ -625,21 +734,32 @@ class Executor:
                                 fut._tickets, fut.interrupted
                             )
                     self._exec_write_stage(
-                        fut, batch, deadlines, results, stage, trace
+                        flags, batch, deadlines, results, stage, trace
                     )
                 else:
                     self._exec_read_stage(
-                        fut, batch, deadlines, results, stage, trace
+                        flags, batch, deadlines, results, stage, trace
                     )
 
-    def _precheck(self, fut, deadlines, results, idxs) -> list[int]:
+    def _count_coalesced(self, stages, owners) -> None:
+        """One coalesced group, and its members, per shard it reads."""
+        for stage in stages:
+            for g in stage.groups.values():
+                idxs = g.gets + [i for i, _ in g.mgets]
+                for v in g.scans.values():
+                    idxs += v
+                c_groups, c_requests = self._c_coalesced[g.shard]
+                c_groups.inc()
+                c_requests.inc(len({owners[i] for i in idxs}))
+
+    def _precheck(self, flags, deadlines, results, idxs) -> list[int]:
         """Mark cancelled/expired ops among ``idxs``; return survivors."""
         now = time.monotonic()
         out = []
         for i in idxs:
             if results[i] is not None:
                 continue
-            if fut.interrupted.is_set():
+            if flags[i].is_set():
                 results[i] = OpResult(status=OpStatus.CANCELLED)
             elif deadlines[i] is not None and deadlines[i] <= now:
                 results[i] = OpResult(status=OpStatus.DEADLINE_EXCEEDED)
@@ -647,25 +767,25 @@ class Executor:
                 out.append(i)
         return out
 
-    def _interrupt_for(self, fut, deadline_at):
+    def _interrupt_for(self, flag, deadline_at):
         """Cooperative checker threaded into cursor loops (mid-op
-        deadline/cancel), or None when the op can't be interrupted."""
+        deadline/cancel on the op's cancel event ``flag``)."""
         if deadline_at is None:
             def check():
-                if fut.interrupted.is_set():
+                if flag.is_set():
                     raise OpInterrupted(OpStatus.CANCELLED)
         else:
             def check():
-                if fut.interrupted.is_set():
+                if flag.is_set():
                     raise OpInterrupted(OpStatus.CANCELLED)
                 if time.monotonic() > deadline_at:
                     raise OpInterrupted(OpStatus.DEADLINE_EXCEEDED)
         return check
 
     # ---- writes ----
-    def _exec_write_stage(self, fut, batch, deadlines, results, stage,
+    def _exec_write_stage(self, flags, batch, deadlines, results, stage,
                           trace=None):
-        live = self._precheck(fut, deadlines, results, stage.ops)
+        live = self._precheck(flags, deadlines, results, stage.ops)
         if not live:
             return
         # Put/Delete rows accumulate per shard and group-commit together;
@@ -768,7 +888,7 @@ class Executor:
                 self.stores[si]._apply_delete_range(l, h)
 
     # ---- reads ----
-    def _exec_read_stage(self, fut, batch, deadlines, results, stage,
+    def _exec_read_stage(self, flags, batch, deadlines, results, stage,
                          trace=None):
         groups = sorted(
             stage.groups.values(), key=lambda g: (-g.priority, g.shard)
@@ -792,22 +912,23 @@ class Executor:
                            gets=len(g.gets) + len(g.mgets),
                            scans=sum(len(v) for v in g.scans.values())):
                     self._exec_points(
-                        fut, batch, deadlines, results, g, view, mg
+                        flags, batch, deadlines, results, g, view, mg
                     )
-                    self._exec_scans(fut, batch, deadlines, results, g, view)
+                    self._exec_scans(flags, batch, deadlines, results, g,
+                                     view)
             for i, (found, vals) in mg.items():
                 if results[i] is None:
                     results[i] = OpResult(
                         status=OpStatus.OK, found=found, vals=vals
                     )
 
-    def _exec_points(self, fut, batch, deadlines, results, g, view, mg):
-        gets = self._precheck(fut, deadlines, results, g.gets)
+    def _exec_points(self, flags, batch, deadlines, results, g, view, mg):
+        gets = self._precheck(flags, deadlines, results, g.gets)
         mgets = [
             (i, pos)
             for i, pos in g.mgets
             if results[i] is None
-            and self._precheck(fut, deadlines, results, [i])
+            and self._precheck(flags, deadlines, results, [i])
         ]
         if len(gets) == 1 and not mgets:
             # lone point lookup: the scalar read path (same results as the
@@ -895,10 +1016,10 @@ class Executor:
             mg[i][0][pos] = f
             mg[i][1][pos] = v
 
-    def _exec_scans(self, fut, batch, deadlines, results, g, view):
+    def _exec_scans(self, flags, batch, deadlines, results, g, view):
         for with_vals, idxs in g.scans.items():
             with _tracing.span("scan_args"):
-                live = self._precheck(fut, deadlines, results, idxs)
+                live = self._precheck(flags, deadlines, results, idxs)
                 if not live:
                     continue
                 starts = np.array(
@@ -906,7 +1027,7 @@ class Executor:
                 )
                 ns = np.array([batch.ops[i].n for i in live], np.int64)
                 checks = [
-                    self._interrupt_for(fut, deadlines[i]) for i in live
+                    self._interrupt_for(flags[i], deadlines[i]) for i in live
                 ]
             try:
                 rows = self.stores[g.shard]._scan_group_at(
@@ -947,7 +1068,7 @@ class Executor:
                     kk, vv = self._clip_to_span(g.shard, kk, vv)
                     try:
                         kk, vv = self._drain_scan(
-                            fut, deadlines[i], g.shard, kk, vv,
+                            flags[i], deadlines[i], g.shard, kk, vv,
                             batch.ops[i].n, with_vals, view,
                         )
                     except OpInterrupted as e:
@@ -969,12 +1090,12 @@ class Executor:
         keep = int(np.searchsorted(kk, np.uint64(hi), side="left"))
         return kk[:keep], None if vv is None else vv[:keep]
 
-    def _drain_scan(self, fut, deadline_at, shard, kk, vv, n, with_vals,
+    def _drain_scan(self, flag, deadline_at, shard, kk, vv, n, with_vals,
                     view):
         """Cross-shard fan-out of one scan: drain follow-on shards in key
         order until ``n`` rows (the serve engine's legacy drain rule)."""
         si = shard + 1
-        check = self._interrupt_for(fut, deadline_at)
+        check = self._interrupt_for(flag, deadline_at)
         while len(kk) < n and si < len(self.stores):
             check()
             k2, v2 = self.stores[si]._scan_at(

@@ -38,6 +38,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import itertools
 import os
 import tempfile
 import threading
@@ -447,6 +448,9 @@ class RemixDB:
         # the frozen MemTable's range tombstones, visible to readers for
         # the same window: they become partition excised spans at publish
         self._flush_ranges: list | None = None
+        # (MemTable layer dict, its length, its keys sorted as uint64):
+        # batched scans sort only the keys added since (_overlay_keys)
+        self._okeys_cache: tuple | None = None
         self.versions = VersionSet(on_release=self._on_version_release,
                                    registry=self.registry)
         self.versions.publish(
@@ -1732,8 +1736,10 @@ class RemixDB:
         alone can't distinguish "partition tail reached" from "window
         swallowed by a tombstone run or a partition boundary", and the
         cursor handles both (so promotion never changes results).
-        Batches over a non-empty overlay take the cursor path per query,
-        like the legacy ``scan_batch`` did.
+        A non-empty MemTable overlay is sorted once per group and each
+        query's overlay slice merged into its window
+        (:meth:`_merge_overlay`); unflushed range tombstones send the
+        group to the cursor per query.
 
         Returns one entry per query: ``(keys (M,), vals (M, VW))`` with
         ``vals`` None when ``with_vals`` is False, or the
@@ -1763,10 +1769,9 @@ class RemixDB:
             # path pipelines value/tomb blocks ahead (Fig 10,
             # prefetch_depth) — the batched window path instead
             # coalesces across queries, which only wins with > 1 scan
-            # sharing granules. Batches over a non-empty overlay
-            # (entries or unflushed range tombstones) merge per query
-            # through the cursor too.
-            by_cursor = q == 1 or view.overlay or view.ranges
+            # sharing granules. Unflushed range tombstones merge per
+            # query through the cursor too.
+            by_cursor = q == 1 or bool(view.ranges)
             if by_cursor:
                 self._c_scan_fallback["lone" if q == 1 else "overlay"].inc(
                     int(act.sum())
@@ -1776,6 +1781,7 @@ class RemixDB:
                 spans = partition_spans([p.lo for p in parts])
                 pidx = route_host([p.lo for p in parts], starts)
                 widths = ns + np.maximum(8, ns // 2)
+                now = clock.now()
 
         def row_fallback(qi):
             try:
@@ -1791,71 +1797,159 @@ class RemixDB:
                 out[qi] if out[qi] is not None else row_fallback(qi)
                 for qi in range(q)
             ]
+        okeys = None
+        if view.overlay:
+            with _tracing.span("overlay_sort"):
+                okeys = self._overlay_keys(view)
         for pi in np.unique(pidx[act]):
             sel = np.flatnonzero((pidx == pi) & act)
             p = parts[pi]
             hi = spans[pi][1]
-
-            def emit_row(qi, kk, vv):
-                nn = int(ns[qi])
-                m = kk < hi  # clip to the partition's key span
-                kk = kk[m][:nn]
-                if len(kk) < nn:
-                    self._c_scan_fallback["underfull"].inc()
-                    out[qi] = row_fallback(qi)
-                    return
-                out[qi] = (kk, vv[m][:nn] if with_vals else None)
-
             with _tracing.span("route"):
                 cold = self._cold_ok(p)
                 dv = None if cold else self._device_view(p)
             if cold:
                 # per-query widths: the coalesced fetch set merges row
                 # windows across different n values (shared granules)
-                for qi, (kk, vv, _) in zip(
-                    sel, p.cold_scan_batch(starts[sel], widths[sel])
-                ):
-                    emit_row(qi, kk, vv)
+                rows = [(qi, kk, vv) for qi, (kk, vv, _) in zip(
+                    sel, p.cold_scan_batch(starts[sel], widths[sel]))]
+            elif dv is not None:
+                # promoted: one fixed-width window call per partition
+                # (jit shape-stability); max width over the group,
+                # per-query n clipping keeps results bit-identical to
+                # per-n groups
+                rows = [(qi, kk, vv) for qi, (kk, vv) in zip(
+                    sel, self.device_views.scan_windows(
+                        dv, starts[sel], int(widths[sel].max()), now,
+                        with_vals=with_vals))]
+            else:
+                rows = self._index_windows(p, sel, starts,
+                                           int(widths[sel].max()),
+                                           with_vals)
+            # clip each window to the partition's key span, merge its
+            # overlay slice, keep the first n entries; a row left short
+            # goes to the cursor
+            if hi < 1 << 64:
+                clipped = []
+                for qi, kk, vv in rows:
+                    cut = int(kk.searchsorted(np.uint64(hi)))
+                    clipped.append((qi, kk[:cut],
+                                    None if vv is None else vv[:cut]))
+                rows = clipped
+            if okeys is not None:
+                with _tracing.span("overlay_merge"):
+                    rows = self._merge_overlay(view, okeys, rows, starts,
+                                               now)
+            short = []
+            for qi, kk, vv in rows:
+                nn = int(ns[qi])
+                if len(kk) < nn:
+                    short.append(qi)
+                else:
+                    out[qi] = (kk[:nn], vv[:nn] if with_vals else None)
+            for qi in short:
+                self._c_scan_fallback["underfull"].inc()
+                out[qi] = row_fallback(qi)
+        return out
+
+    def _index_windows(self, p: Partition, sel, starts, width: int,
+                       with_vals: bool) -> list:
+        """One jitted window call over the partition's ``p.index()``
+        arrays for the queries ``sel``: ``(qi, keys, vals)`` rows of live
+        entries."""
+        with _tracing.span("launch"):
+            remix, runset = p.index()
+            sq = starts[sel]
+            pad = _pow2pad(len(sq))
+            sq = np.pad(sq, (0, pad - len(sq)))
+            qk = jnp.asarray(CK.pack_u64(sq))
+            kw = dict(self._qkw())
+            if not self.cfg.use_kernels:
+                # skip the value gather (XLA dead-code-eliminates it)
+                # when the caller only needs keys, e.g. scan_batch
+                kw["with_vals"] = with_vals
+            keys, vals, valid, _ = self._query_mod().scan(
+                remix, runset, qk, width=width, **kw
+            )
+            self._c_launches.inc()
+        (keys,) = _tracing.fetch(self._c_syncs, keys)
+        (valid,) = _tracing.fetch(self._c_syncs, valid)
+        if vals is not None:
+            (vals,) = _tracing.fetch(self._c_syncs, vals)
+        keys = CK.unpack_u64(keys)[: len(sel)]
+        valid = valid[: len(sel)]
+        vals = None if vals is None else vals[: len(sel)]
+        return [
+            (qi, keys[row][valid[row]],
+             vals[row][valid[row]] if vals is not None else None)
+            for row, qi in enumerate(sel)
+        ]
+
+    def _overlay_keys(self, view: Snapshot) -> np.ndarray:
+        """The overlay's keys, sorted, as uint64. A MemTable layer only
+        ever gains keys and a dict iterates in insertion order, so for a
+        one-layer overlay the keys added since the last call are the
+        dict's last ones: only those are sorted in. A shared view's
+        overlay is the live MemTable: read it under the writer lock."""
+        layers = getattr(view.overlay, "layers", ())
+        lock = self._state_lock if view.shared else contextlib.nullcontext()
+        if len(layers) != 1:
+            with lock:
+                okeys = np.fromiter(view.overlay, np.uint64)
+            okeys.sort()
+            return okeys
+        d, cached = layers[0], self._okeys_cache
+        with lock:
+            n = len(d)
+            if cached is not None and cached[0] is d:
+                new = list(itertools.islice(reversed(d), n - cached[1]))
+            else:
+                new, cached = list(d), None
+        add = np.array(new, np.uint64)
+        add.sort()
+        if cached is not None:
+            old = cached[2]
+            add = np.insert(old, old.searchsorted(add), add)
+        self._okeys_cache = (d, n, add)
+        return add
+
+    def _merge_overlay(self, view: Snapshot, okeys, rows, starts,
+                       now) -> list:
+        """Each ``(qi, keys, vals)`` row — the live table entries of a
+        window, every one from ``starts[qi]`` up to the row's last key —
+        merged with the overlay entries in that key range: an overlay
+        entry replaces the table entry of its key, and a tombstone or an
+        expired entry drops out with it. That is the cursor's merge rule,
+        so a row's first entries equal the cursor's. ``vals`` is None for
+        a keys-only scan."""
+        if not rows:
+            return rows
+        lasts = np.array([kk[-1] if len(kk) else 0 for _, kk, _ in rows],
+                         np.uint64)
+        qis = [qi for qi, _, _ in rows]
+        los = okeys.searchsorted(starts[qis]).tolist()
+        his = okeys.searchsorted(lasts, side="right").tolist()
+        out = []
+        for (qi, kk, vv), lo, hi in zip(rows, los, his):
+            if lo >= hi or not len(kk):
+                out.append((qi, kk, vv))
                 continue
-            # promoted: one fixed-width window call per partition (jit
-            # shape-stability); max width over the group, per-query n
-            # clipping keeps results bit-identical to per-n groups
-            width = int(widths[sel].max())
-            if dv is not None:
-                for qi, (kk, vv) in zip(
-                    sel,
-                    self.device_views.scan_windows(
-                        dv, starts[sel], width, clock.now(),
-                        with_vals=with_vals,
-                    ),
-                ):
-                    emit_row(qi, kk, vv)
-                continue
-            with _tracing.span("launch"):
-                remix, runset = p.index()
-                sq = starts[sel]
-                pad = _pow2pad(len(sq))
-                sq = np.pad(sq, (0, pad - len(sq)))
-                qk = jnp.asarray(CK.pack_u64(sq))
-                kw = dict(self._qkw())
-                if not self.cfg.use_kernels:
-                    # skip the value gather (XLA dead-code-eliminates it)
-                    # when the caller only needs keys, e.g. scan_batch
-                    kw["with_vals"] = with_vals
-                keys, vals, valid, _ = self._query_mod().scan(
-                    remix, runset, qk, width=width, **kw
-                )
-                self._c_launches.inc()
-            (keys,) = _tracing.fetch(self._c_syncs, keys)
-            (valid,) = _tracing.fetch(self._c_syncs, valid)
-            if vals is not None:
-                (vals,) = _tracing.fetch(self._c_syncs, vals)
-            keys = CK.unpack_u64(keys)[: len(sel)]
-            valid = valid[: len(sel)]
-            vals = None if vals is None else vals[: len(sel)]
-            for row, qi in enumerate(sel):
-                v = vals[row][valid[row]] if vals is not None else None
-                emit_row(qi, keys[row][valid[row]], v)
+            ok = okeys[lo:hi]
+            ents = [view.overlay.get(k) for k in ok.tolist()]
+            live = np.array([not entry_dead(e, now) for e in ents], bool)
+            pos = kk.searchsorted(ok)
+            same = kk[np.minimum(pos, len(kk) - 1)] == ok
+            keep = np.ones(len(kk), bool)
+            keep[pos[same]] = False  # shadowed by the overlay entry
+            keys = np.concatenate([kk[keep], ok[live]])
+            order = keys.argsort(kind="stable")
+            if vv is not None:
+                ov = [e.val for e, alive in zip(ents, live) if alive]
+                vv = np.concatenate(
+                    [vv[keep],
+                     np.array(ov, np.uint32).reshape(len(ov), vv.shape[1])]
+                )[order]
+            out.append((qi, keys[order], vv))
         return out
 
     # ---------------- stats / recovery ----------------

@@ -54,11 +54,16 @@ from repro.obs import tracing as _tracing
 from repro.obs.tracing import fetch
 
 
-def _pow2pad(n: int) -> int:
-    b = 8
+def _pow2pad(n: int, least: int = 8) -> int:
+    b = least
     while b < n:
         b <<= 1
     return b
+
+
+# fewest queries a batched scan launches: one program per window width
+# then serves every group of up to this many scans
+SCAN_QUERIES = 32
 
 
 @dataclasses.dataclass
@@ -125,6 +130,8 @@ class DeviceViewManager:
         self._c_syncs = registry.counter("device_syncs")
         self._c_rows = registry.counter("device_rows_gathered")
         self._c_fallback = registry.counter("device_fallback_total")
+        self._c_scan_calls = registry.counter("scan_live_calls")
+        self._c_scan_queries = registry.counter("scan_live_queries")
         registry.gauge("hbm_resident_bytes", fn=lambda: self._resident)
 
     # ---- residency ----
@@ -234,38 +241,44 @@ class DeviceViewManager:
         with_vals: bool = True,
     ) -> list:
         """Batched scan-window resolution: per query ``(keys (M,) u64,
-        vals (M, VW) | None)`` — live entries of a ``width``-slot view
-        window, same semantics as the host `gather_view` path."""
+        vals (M, VW) | None)`` — live entries of a window of at least
+        ``width`` slots, same semantics as the host `gather_view` path.
+
+        The launched shape is a power-of-two query count, at least
+        ``SCAN_QUERIES``, and the width rounded up to whole groups of the
+        view's D: the decode covers ceil(width / D) + 1 groups at any
+        width, so the rounding adds no decode work. Groups of up to
+        ``SCAN_QUERIES`` scans of at most 100 keys (widths up to 150)
+        then need at most five programs."""
         starts_u64 = np.asarray(starts_u64, np.uint64)
         q = len(starts_u64)
+        d = int(dv.remix.d)
+        width = -(-int(width) // d) * d
         if dv.tier != "full" and with_vals:
             return self._scan_pipelined(dv, starts_u64, width, now)
         with _tracing.span("launch"):
-            nw = jnp.uint32(int(now))
-            pad = _pow2pad(q)
+            pad = _pow2pad(q, SCAN_QUERIES)
             sq = np.pad(starts_u64, (0, pad - q))
-            qk = jnp.asarray(CK.pack_u64(sq))
             kd, vd, md, *rest = ops.scan_live(
-                dv.remix, dv.runset, dv.exp, qk, nw, width=width,
-                interpret=self.interpret,
+                dv.remix, dv.runset, dv.exp, CK.pack_u64(sq),
+                np.uint32(int(now)), width=width, interpret=self.interpret,
             )
             self._c_launches.inc()
             self._c_batches.inc()
+            self._c_scan_calls.inc()
+            self._c_scan_queries.inc(q)
         if with_vals:
             keys, vals, valid = fetch(self._c_syncs, kd, vd, md)
         else:
             keys, valid = fetch(self._c_syncs, kd, md)
             vals = None
         with _tracing.span("unpack"):
-            del qk, nw, kd, vd, md, rest  # frees the device buffers
-            out = []
-            rows = 0
-            for i in range(q):
-                m = valid[i]
-                kk = CK.unpack_u64(keys[i][m])
-                rows += len(kk)
-                out.append((kk, vals[i][m] if with_vals else None))
-            self._c_rows.inc(rows)
+            del kd, vd, md, rest  # frees the device buffers
+            keys = CK.unpack_u64(keys[:q])
+            out = [(keys[i][valid[i]],
+                    vals[i][valid[i]] if with_vals else None)
+                   for i in range(q)]
+            self._c_rows.inc(int(valid[:q].sum()))
         return out
 
     def _scan_pipelined(self, dv, starts_u64, width, now) -> list:
@@ -289,6 +302,8 @@ class DeviceViewManager:
                     interpret=self.interpret,
                 )
                 self._c_launches.inc()
+                self._c_scan_calls.inc()
+                self._c_scan_queries.inc(min(s, q - si * s))
             return out
 
         out: list = []

@@ -738,8 +738,13 @@ def test_device_boundary_counters(tmp_path):
         == (1, 1, 1)
     assert d["device_launches"] == 1 + d["cursor_windows"]
     assert d["device_syncs"] == 1 + d["cursor_windows"]
-    # a non-empty MemTable overlay: every scan of the group by cursor
+    # a non-empty MemTable overlay: still one fused window launch and
+    # its sync, the overlay merged into the windows on the host
     db.put(5, [1, 2])
+    assert delta(Batch().scan(k[3], 9).scan(k[50], 9)) == {
+        "device_launches": 1, "device_syncs": 1, "device_batches": 1}
+    # an unflushed range tombstone: every scan of the group by cursor
+    db.delete_range(1, 2)
     assert delta(Batch().scan(k[3], 9).scan(k[50], 9)) == {
         "device_launches": 2, "device_syncs": 2, "cursor_seeks": 2,
         "cursor_windows": 2, "overlay": 2}

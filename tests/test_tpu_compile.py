@@ -132,3 +132,17 @@ def test_cursor_window_buffer_compiles(one_chip, width, seek, kernels):
                                         opts=opts),
         remix, runset, at, kernel=kernels,
     )
+
+
+@pytest.mark.parametrize("q,width", [(32, 32), (32, 160), (64, 160)])
+def test_scan_live_group_shapes_compile(one_chip, q, width):
+    """The padded shapes a coalesced group of scans launches
+    (``DeviceViewManager.scan_windows``: a power-of-two query count and
+    the width in whole groups of D)."""
+    remix, runset, exp, now = _view(one_chip)
+    _compile_has_kernel(
+        lambda rm, rs, e, qk, t: ops.scan_live(
+            rm, rs, e, qk, t, width=width, interpret=False
+        ),
+        remix, runset, exp, _sds(one_chip, (q, KW), jnp.uint32), now,
+    )
