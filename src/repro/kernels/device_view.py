@@ -31,11 +31,13 @@ can never alias a recycled ``id()``.
 
 Every read path crosses the device boundary through the same three
 steps, each a span under an active trace and counted in the store's
-registry: ``launch`` (pack the queries, copy them and the ``now`` scalar
-to the device, dispatch the jitted call; ``device_launches``),
+registry: ``launch`` (pack the queries into host numpy arrays and call
+the jitted program with them and a numpy ``now`` scalar, so the copies
+to the device ride in the call's own dispatch; ``device_launches``),
 ``device_wait`` (:func:`repro.obs.tracing.fetch`, one blocking
 device→host transfer; ``device_syncs``) and ``unpack`` (host work on
-the fetched result).
+the fetched result). A point lookup's result is one packed buffer
+(:func:`repro.kernels.ops.unpack_get`).
 ``benchmarks/kernels_bench.py`` asserts the fused batch-256 get pipeline
 pays exactly one sync per batch.
 """
@@ -202,37 +204,31 @@ class DeviceViewManager:
 
     # ---- fused batched execution ----
     def get_batch(self, dv: DeviceView, keys_u64, now) -> tuple:
-        """Batched point gets. Full tier: one fused device composition +
-        one result fetch. Index tier: the same single round trip returns
-        (found, run, row) and values come from the host block cache."""
+        """Batched point gets: one fused device composition and one fetch
+        of its packed result. The full tier reads the values from it; the
+        index tier reads (found, run, row) and gathers the values from
+        the host block cache."""
         with _tracing.span("launch"):
             keys_u64 = np.asarray(keys_u64, np.uint64)
             q = len(keys_u64)
             pad = _pow2pad(q)
             kq = np.pad(keys_u64, (0, pad - q))
-            qk = jnp.asarray(CK.pack_u64(kq))
-            nw = jnp.uint32(int(now))
-            fd, vd, rid_d, row_d = ops.get_live(
-                dv.remix, dv.runset, dv.exp, qk, nw, interpret=self.interpret
+            buf_d = ops.get_live(
+                dv.remix, dv.runset, dv.exp, CK.pack_u64(kq),
+                np.uint32(int(now)), interpret=self.interpret,
             )
             self._c_launches.inc()
             self._c_batches.inc()
-        if dv.tier == "full":
-            # THE one host sync
-            found, vals = fetch(self._c_syncs, fd, vd)
-            with _tracing.span("unpack"):
-                del qk, nw, fd, vd, rid_d, row_d  # frees the device buffers
-                found, vals = found[:q], vals[:q]
-                self._c_rows.inc(int(found.sum()))
-            return found, vals
-        found, rid, row = fetch(self._c_syncs, fd, rid_d, row_d)
+        # THE one host sync
+        (buf,) = fetch(self._c_syncs, buf_d)
         with _tracing.span("unpack"):
-            del qk, nw, fd, vd, rid_d, row_d
-            found, rid, row = found[:q], rid[:q], row[:q]
-            vals = np.zeros((q, dv.vw), np.uint32)
-            for r in np.unique(rid[found]):
-                m = found & (rid == r)
-                vals[m] = dv.tables[r].rows_scattered("vals", row[m])
+            del buf_d  # frees the device buffer
+            found, vals, rid, row = ops.unpack_get(buf, pad, q)
+            if dv.tier != "full":
+                vals = np.zeros((q, dv.vw), np.uint32)
+                for r in np.unique(rid[found]):
+                    m = found & (rid == r)
+                    vals[m] = dv.tables[r].rows_scattered("vals", row[m])
             self._c_rows.inc(int(found.sum()))
         return found, vals
 
@@ -291,15 +287,14 @@ class DeviceViewManager:
         padded = np.zeros(nsl * s, np.uint64)
         padded[:q] = starts_u64
         pad = _pow2pad(s)
-        nw = jnp.uint32(int(now))
+        nw = np.uint32(int(now))
 
         def launch(si):
             with _tracing.span("launch"):
                 sq = np.pad(padded[si * s:(si + 1) * s], (0, pad - s))
-                qk = jnp.asarray(CK.pack_u64(sq))
                 out = ops.scan_live(
-                    dv.remix, dv.runset, dv.exp, qk, nw, width=width,
-                    interpret=self.interpret,
+                    dv.remix, dv.runset, dv.exp, CK.pack_u64(sq), nw,
+                    width=width, interpret=self.interpret,
                 )
                 self._c_launches.inc()
                 self._c_scan_calls.inc()

@@ -15,6 +15,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import keys as K
 from repro.core.remix import Remix
@@ -201,6 +202,10 @@ def scan_live(
 
 @partial(jax.jit, static_argnames=("interpret",))
 def get_live(remix, runset, exp, queries, now, *, interpret: bool):
+    """Point lookups of ``queries`` (Q, KW) at clock ``now``, as one flat
+    uint32 buffer so the host makes one fetch: per query, ``found`` as
+    0/1, the view's value words, then the run id and the absolute row the
+    lookup resolved to (:func:`unpack_get`)."""
     queries = jnp.asarray(queries, jnp.uint32)
     pos = seek(remix, runset, queries, interpret=interpret)
     keys, vals, valid, runid, absidx = gather_view_live(
@@ -208,4 +213,18 @@ def get_live(remix, runset, exp, queries, now, *, interpret: bool):
     )
     with jax.named_scope("liveness"):
         found = valid[:, 0] & K.key_eq(keys[:, 0], queries)
-    return found, vals[:, 0], runid[:, 0], absidx[:, 0]
+    u32 = jnp.uint32
+    return jnp.concatenate([
+        found[:, None].astype(u32), vals[:, 0],
+        runid[:, :1].astype(u32), absidx[:, :1].astype(u32),
+    ], axis=1).reshape(-1)
+
+
+def unpack_get(buf: np.ndarray, pad: int, q: int) -> tuple:
+    """The first ``q`` of the ``pad`` queries of a fetched
+    :func:`get_live` buffer: ``found (q,)`` bool, and views ``vals
+    (q, VW)`` of the value words and ``runid``/``absidx (q,)`` int32 of
+    the resolved rows, VW being the view's own value width."""
+    rows = buf.reshape(pad, -1)[:q]
+    return (rows[:, 0].astype(bool), rows[:, 1:-2],
+            rows[:, -2].view(np.int32), rows[:, -1].view(np.int32))

@@ -246,3 +246,119 @@ def test_store_rejects_bad_device_knobs(tmp_path):
         RemixDB.open(str(tmp_path / "a"), _cfg(device_path="maybe"))
     with pytest.raises(ValueError):
         RemixDB.open(str(tmp_path / "b"), _cfg(device_slice=0))
+
+
+# ------------------------------ DeviceViewManager.get_batch, both tiers
+GET_T0 = 2_000_000  # the query clock of the get_batch cases
+
+
+@pytest.fixture(scope="module", params=["full", "index"])
+def get_store(request, tmp_path_factory):
+    """One on-disk partition: live rows, a later run of tombstones, and
+    TTL rows whose expiry is ``GET_T0`` (dead at it) or ``GET_T0 + 1``
+    (live), served from a view in the requested tier. Yields the store,
+    the view, the reference and the probe keys (every key and misses)."""
+    from test_model_store import ModelStore
+
+    tier = request.param
+    root = str(tmp_path_factory.mktemp(f"get_{tier}") / "db")
+    rng = np.random.default_rng(5)
+    keys = rng.choice(1 << 20, 48, replace=False).astype(np.uint64)
+    model = ModelStore()
+    clock.set_source(lambda: GET_T0 - 10)
+    db = RemixDB.open(root, _cfg(device_path="off"))
+    try:
+        for i, k in enumerate(keys.tolist()):
+            db.put(k, [i, i ^ 0x55])
+            model.put(k, [i, i ^ 0x55])
+        db.flush()
+        for k in keys[:8].tolist():
+            db.delete(k)
+            model.delete(k)
+        db.flush()
+        for j, k in enumerate(keys[8:16].tolist()):
+            ttl = 10 + (j & 1)  # expires at GET_T0, or one second later
+            db.put(k, [j, 7], ttl=ttl)
+            model.put(k, [j, 7], GET_T0 - 10 + ttl)
+        db.flush()
+        assert len(db.partitions) == 1
+        p = db.partitions[0]
+        full, idx = p.device_view_bytes(True), p.device_view_bytes(False)
+    finally:
+        db.close()
+        clock.reset()
+    kw = {} if tier == "full" else {"device_budget_bytes": full - 1}
+    assert idx < full - 1
+    db = RemixDB.open(root, _cfg(device_path="on", cold_reads=False, **kw))
+    dv = db.device_views.view_for(db.partitions[0])
+    assert dv.tier == tier
+    probe = np.concatenate([keys, (1 << 20) + keys[:8]])
+    rng.shuffle(probe)
+    yield db, dv, model, probe
+    db.close()
+
+
+def _check_gets(db, dv, model, batch, now):
+    found, vals = db.device_views.get_batch(dv, batch, now)
+    assert found.dtype == bool and found.shape == (len(batch),)
+    assert vals.shape == (len(batch), 2)
+    want = [model.get(k, now) for k in batch.tolist()]
+    np.testing.assert_array_equal(found, [w is not None for w in want])
+    np.testing.assert_array_equal(
+        vals[found], np.array([w for w in want if w is not None],
+                              np.uint32).reshape(-1, 2))
+    return found
+
+
+@pytest.mark.parametrize("q", [1, 7, 8, 9])
+def test_get_batch_matches_model(get_store, q):
+    """Every probe key, in batches of ``q`` under, at and over the
+    least padded launch of 8, agrees bit for bit with the reference:
+    tombstones hide, an expiry equal to ``now`` is dead and one a second
+    later is live."""
+    db, dv, model, probe = get_store
+    n = len(probe)
+    for i in range(0, n, q):
+        _check_gets(db, dv, model, probe[(i + np.arange(q)) % n], GET_T0)
+
+
+def test_get_live_now_is_traced(get_store):
+    """A new clock value reuses the compiled lookup: ``now`` is a traced
+    argument, and liveness still follows it."""
+    from repro.kernels import ops
+
+    db, dv, model, probe = get_store
+    _check_gets(db, dv, model, probe, GET_T0)
+    size = ops.get_live._cache_size()
+    live = [int(_check_gets(db, dv, model, probe, t).sum())
+            for t in (GET_T0 - 3, GET_T0 + 7)]
+    assert ops.get_live._cache_size() == size
+    assert live[0] == live[1] + 8  # every TTL row expired in between
+
+
+def test_lone_get_one_launch_one_fetch(get_store, monkeypatch):
+    """A lone get launches once, syncs once and fetches one array."""
+    from repro.kernels import device_view
+
+    db, dv, model, probe = get_store
+    fetched = []
+    real = device_view.fetch
+
+    def spy(syncs, *arrays):
+        fetched.append(len(arrays))
+        return real(syncs, *arrays)
+
+    monkeypatch.setattr(device_view, "fetch", spy)
+    names = ("device_launches", "device_syncs")
+    key = next(k for k in probe.tolist() if model.get(k, GET_T0))
+    clock.set_source(lambda: GET_T0)
+    try:
+        s0 = [db.registry.counter(n).value for n in names]
+        v = db.get(key)
+        delta = [db.registry.counter(n).value - v0
+                 for n, v0 in zip(names, s0)]
+    finally:
+        clock.reset()
+    assert delta == [1, 1]
+    assert fetched == [1]
+    np.testing.assert_array_equal(v, model.get(key, GET_T0))

@@ -769,10 +769,14 @@ def test_kernel_stages_in_lowered_get_live():
                     vals=s((r, n, vw), jnp.uint32),
                     seq=s((r, n), jnp.uint32), tomb=s((r, n), jnp.bool_),
                     lens=s((r,), jnp.int32))
-    text = ops.get_live.lower(
+    lowered = ops.get_live.lower(
         remix, runset, s((r, n), jnp.uint32), s((q, kw), jnp.uint32),
         s((), jnp.uint32), interpret=True,
-    ).as_text(debug_info=True)
+    )
+    # the result is one packed uint32 buffer of 1 + VW + 2 words a query
+    out = lowered.out_info
+    assert (out.shape, out.dtype) == ((q * (1 + vw + 2),), jnp.uint32)
+    text = lowered.as_text(debug_info=True)
     for scope in ("remix_seek", "remix_gather", "liveness"):
         assert scope in text, scope
 

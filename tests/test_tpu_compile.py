@@ -100,11 +100,14 @@ def _view(sharding):
 
 def test_fused_get_and_scan_compile(one_chip):
     remix, runset, exp, now = _view(one_chip)
-    _compile_has_kernel(
-        lambda rm, rs, e, q, t: ops.get_live(rm, rs, e, q, t,
-                                             interpret=False),
+    lowered = ops.get_live.lower(
         remix, runset, exp, _sds(one_chip, (Q_GET, KW), jnp.uint32), now,
+        interpret=False,
     )
+    # one packed buffer: per query found, VW value words, run id and row
+    out = lowered.out_info
+    assert (out.shape, out.dtype) == ((Q_GET * (1 + VW + 2),), jnp.uint32)
+    assert "tpu_custom_call" in lowered.compile().as_text()
     _compile_has_kernel(
         lambda rm, rs, e, q, t: ops.scan_live(
             rm, rs, e, q, t, width=SCAN_WIDTH, interpret=False
