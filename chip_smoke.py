@@ -10,14 +10,18 @@ batches through ``submit()``:
 
 - batch-256 multi-gets, about 1/8 of them misses;
 - batch-64 Seek+Next50 scans;
-- lone Seek+Next50 scans, which take the streaming cursor path;
+- lone Seek+Next50 scans, which take the streaming cursor path over the
+  partitions' device views;
 - puts and deletes, each group followed by a flush.
 
 Every answer is compared with a plain numpy reference built from the same
-seed, and every op must come back ``OK``. The store's own counters must
-show device batches, no fallback off the device, at least 512 MiB of
-resident views, kernels compiled (not interpreted), and exactly one host
-sync per partition of each get batch.
+seed, and every op must come back ``OK``. At the end, lone scans at
+fresh starts are answered once more by the store reopened without
+device views (the cursor over the legacy ``p.index()`` copy, read by
+``core.query``) and must match the view's answers bit for bit. The
+store's own counters must show device batches, no fallback off the
+device, at least 512 MiB of resident views, kernels compiled (not
+interpreted), and exactly one host sync per partition of each get batch.
 
     python chip_smoke.py [--keys N] [--seed S] [--rounds R]
 
@@ -53,6 +57,7 @@ GET_BATCH = 256
 SCAN_BATCH = 64
 SCAN_N = 50
 WRITE_GROUPS = 4  # put/delete + flush groups spread over the rounds
+LEGACY_SCANS = 16  # lone scans replayed over the legacy copy at the end
 
 
 def log(msg: str) -> None:
@@ -152,7 +157,7 @@ def run_smoke(n_keys: int, *, seed: int = 0, rounds: int = 64,
                    tables=sum(len(p.tables) for p in parts))
         db.close()
         del db, parts
-        gc.collect()  # drop the load store's device arrays
+        gc.collect()  # drop the load store's arrays
         out["load_s"] = time.perf_counter() - t0
         log(f"smoke load: {n_keys} keys in {out['load_s']:.1f} s -> "
             f"{out['partitions']} partitions, {out['tables']} tables, "
@@ -212,6 +217,7 @@ def run_smoke(n_keys: int, *, seed: int = 0, rounds: int = 64,
                 k, v = ref.scan(s, SCAN_N)
                 check(np.array_equal(r.keys, k), f"{kind} keys != reference")
                 check(np.array_equal(r.vals, v), f"{kind} vals != reference")
+            return res
 
         def scan_starts(n):
             return np.concatenate([
@@ -274,6 +280,9 @@ def run_smoke(n_keys: int, *, seed: int = 0, rounds: int = 64,
             trail = promote()  # views of partitions a flush replaced
             log(f"smoke re-promotion after the last flush: resident after "
                 f"each round: {trail}")
+            # lone scans on the final state, for the legacy replay below
+            lone = [(s, scans("lone_scan", np.array([s], np.uint64))[0])
+                    for s in scan_starts(2 * LEGACY_SCANS)[::2].tolist()]
 
             out["batches"] = sum(len(v) for k, v in times.items()
                                  if k != "flush")
@@ -295,6 +304,22 @@ def run_smoke(n_keys: int, *, seed: int = 0, rounds: int = 64,
         finally:
             eng.close()
             db.close()
+        del eng, db, dvm
+        gc.collect()  # drop the views' device arrays
+
+        # ---- the cursor over the view against the cursor over the
+        # legacy p.index() copy, at the same starts and state
+        legacy = RemixDB.open(root, dataclasses.replace(
+            cfg, device_path="off", cold_reads=False))
+        try:
+            check(legacy.device_views is None, "legacy store has views")
+            for s, r in lone:
+                k, v = legacy.scan(s, SCAN_N)
+                check(np.array_equal(k, r.keys) and np.array_equal(v, r.vals),
+                      f"lone scan at {s}: view and legacy copy disagree")
+            out["legacy_scans"] = len(lone)
+        finally:
+            legacy.close()
     stats = jax.devices()[0].memory_stats() or {}
     out["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
     log(f"smoke batches: {out['batches']} through submit() "
@@ -305,8 +330,9 @@ def run_smoke(n_keys: int, *, seed: int = 0, rounds: int = 64,
         f"hbm_resident_bytes={out['hbm_resident_bytes']} "
         f"interpret={out['interpret']} tiers={out['upload_tiers']} "
         f"peak_bytes_in_use={out['peak_bytes_in_use']}")
-    log("smoke parity: every answer matched the numpy reference; "
-        "0 non-OK ops")
+    log(f"smoke parity: every answer matched the numpy reference; "
+        f"0 non-OK ops; {out['legacy_scans']} lone scans over the views "
+        f"matched the legacy p.index() copy")
     check(out["device_batches"] > 0, "no batch ran on the device")
     check(out["device_fallback_total"] == 0,
           "a partition fell back off the device")

@@ -30,6 +30,14 @@ from repro.core.runs import RunSet
 from repro.core.view import NEWEST_BIT, PLACEHOLDER
 
 
+def ingroup_for_backend(backend: str | None = None) -> str:
+    """The in-group search mode for ``backend`` (default: the attached
+    one): binary probes on the CPU, where gathers are scalar-expensive,
+    and the all-slot vector compare elsewhere (§Perf)."""
+    backend = backend or jax.default_backend()
+    return "binary" if backend == "cpu" else "vector"
+
+
 def decode_groups(remix: Remix, runset: RunSet, g: jnp.ndarray):
     """Decode whole groups. ``g``: any int32 shape (clamped to valid range).
 

@@ -56,11 +56,23 @@ class Remix:
 
 def build_remix(runs: Sequence[Run], d: int = 32) -> tuple[Remix, RunSet]:
     """Build a REMIX over ``runs``; returns (index, stacked run set)."""
-    runset = stack_runs(list(runs))
-    run_keys = [np.asarray(r.keys) for r in runs]
-    run_seqs = [np.asarray(r.seq) for r in runs]
-    layout = V.build_view(run_keys, run_seqs, d)
-    return _remix_from_layout(layout, run_keys, len(runs)), runset
+    remix = remix_from_keys(
+        [np.asarray(r.keys) for r in runs], [np.asarray(r.seq) for r in runs],
+        d,
+    )
+    return remix, stack_runs(list(runs))
+
+
+def remix_from_keys(
+    run_keys: Sequence[np.ndarray], run_seqs: Sequence[np.ndarray], d: int
+) -> Remix:
+    """Build a REMIX from the runs' (Ni, KW) uint32 keys and (Ni,) uint32
+    sequence numbers alone: the index is a pure function of those two
+    columns, so no value, tombstone or stacked run array is needed."""
+    run_keys = [np.asarray(k, np.uint32) for k in run_keys]
+    layout = V.build_view(run_keys, [np.asarray(s, np.uint32)
+                                     for s in run_seqs], d)
+    return _remix_from_layout(layout, run_keys, len(run_keys))
 
 
 def remix_from_order(

@@ -137,9 +137,9 @@ def _execute(plan: Plan, cfg: CompactionConfig, storage=None) -> ExecResult:
         _persist_tables(outs, storage)
         written = sum(t.bytes() for t in outs)
         # tables were only appended: the clone inherits the built REMIX
-        # so index() rebuilds incrementally; its size counts toward WA
+        # so host_remix() rebuilds incrementally; its size counts toward WA
         p2 = p.clone_with_tables(list(p.tables) + outs, carry_built=True)
-        p2.index()
+        p2.host_remix()
         if storage is not None:
             p2.persist_index(storage)
         return ExecResult(
@@ -158,7 +158,7 @@ def _execute(plan: Plan, cfg: CompactionConfig, storage=None) -> ExecResult:
         outs = chunk_table(merged, cfg.table_cap)
         _persist_tables(outs, storage)
         p2 = p.clone_with_tables(keep + outs)  # table set changed: scratch
-        p2.index()
+        p2.host_remix()
         if storage is not None:
             p2.persist_index(storage)
         written = sum(t.bytes() for t in outs)
@@ -182,7 +182,7 @@ def _execute(plan: Plan, cfg: CompactionConfig, storage=None) -> ExecResult:
             group = outs[i : i + m]
             lo = p.lo if i == 0 else int(group[0].keys[0])
             np_ = Partition(lo=lo, tables=list(group), d=p.d)
-            np_.index()
+            np_.host_remix()
             if storage is not None:
                 np_.persist_index(storage)
             written += np_.remix_bytes

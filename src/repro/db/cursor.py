@@ -9,10 +9,13 @@ stream of live ``(key, value)`` entries:
 - cold partitions (on-disk REMIX walk: one anchors search + bounded CKB
   seeks at ``seek``, then pure selector-stream decodes per window —
   :meth:`repro.db.partition.Partition.cold_cursor_window`),
-- promoted partitions (device REMIX: the seek fused with the first
-  window in one jitted call, then comparison-free ``gather_view``
-  windows from the saved position; each window comes back as one
-  device buffer, :func:`window_buffer`).
+- promoted partitions (the partition's budgeted device view: the seek
+  fused with the first window in one jitted call, ``scan_live``, then
+  comparison-free ``gather_view_live`` windows from the saved position;
+  each window comes back as one device buffer, :func:`window_buffer`).
+  A stream reads every window at the clock it opened at. Where no view
+  serves the partition (views off, or no residency tier fits) the same
+  program reads the legacy ``p.index()`` copy with ``core.query``.
 
 The defining property vs repeated ``scan(start, n)`` calls: a cursor
 seeks **once**. ``next``/``next_batch`` advance a persisted view
@@ -24,20 +27,24 @@ consumers. Because the snapshot pins its Version, iteration is immune to
 concurrent flushes: a compaction publishing a new Version never changes
 what an open cursor returns.
 
-On a promoted partition every window is one launch and one fetch: a
-lone Seek+NextN that fits its first window pays a single device round
-trip. Under an active trace each stream open is a ``cursor_seek`` span
-(``overlay_sort`` on the first, ``route``, then the fused seek and first
-window's ``launch``, ``device_wait`` and ``unpack``) and each window
-pull a ``cursor_window`` span (for a later device window ``launch``,
-one ``device_wait`` and ``unpack``; then ``merge``). The device calls
+On a promoted partition every window is one launch, with one host
+argument (the start key's words or the saved position, and the stream's
+``now``), and one fetch: a lone Seek+NextN that fits its first window
+pays a single device round trip. Under an active trace each stream
+open is a ``cursor_seek`` span (``overlay_sort`` on the first,
+``route``, then the fused seek and first window's ``launch``,
+``device_wait`` and ``unpack``) and each window pull a
+``cursor_window`` span (for a later device window ``launch``, one
+``device_wait`` and ``unpack``; then ``merge``). The device calls
 count in the store's ``cursor_seeks`` (stream opens on a promoted
 partition), ``cursor_windows`` (every device window, the fused first
-one included), ``device_launches`` and ``device_syncs``.
+one included), ``device_launches`` and ``device_syncs`` — not in the
+views' ``device_batches`` or ``scan_live_*``.
 """
 from __future__ import annotations
 
 import bisect
+import dataclasses
 from functools import partial
 
 import numpy as np
@@ -46,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import keys as CK
+from repro.core import query as Q
 from repro.db import clock
 from repro.db.memtable import entry_dead
 from repro.db.sharded import partition_spans, route_one
@@ -54,35 +62,82 @@ from repro.obs import tracing as _tracing
 _MAX_WIDTH = 4096  # widening cap over tombstone/old-version runs
 
 
-@partial(jax.jit, static_argnames=("op", "width", "opts"))
-def window_buffer(remix, runset, at, *, op, width: int, opts: tuple = ()):
+@partial(jax.jit, static_argnames=("width", "seek", "rows", "interpret"))
+def window_buffer(remix, runset, exp, at, *, width: int, seek: bool,
+                  rows: bool = False, interpret: bool = False):
     """One cursor window as one device buffer, in one program.
 
-    ``op`` is a query module's ``scan`` (``at``: start keys (Q, KW); the
-    seek fused with the first window) or its ``gather_view`` (``at``:
-    saved view positions (Q,) int32); ``opts`` are its keyword options
-    as ``(name, value)`` pairs. The outputs are packed into one flat
-    uint32 array — key words, value words, validity as 0/1, then the
-    view positions the window starts at — so the host makes one fetch
-    (:func:`unpack_window`) where it would make one per output."""
-    out = op(remix, runset, at, width=width, **dict(opts))
-    keys, vals, valid = out[:3]
-    pos = out[3] if len(out) > 3 else at
+    ``at`` (1, A) uint32 is the call's one host argument: with ``seek``
+    the start key's words (the seek fused with the first window), else
+    the saved view position; its last word is the stream's ``now``.
+    Over a device view (``exp`` its TTL expiry words) the window is
+    :func:`repro.kernels.ops.scan_live` or ``gather_view_live``, which
+    evaluate liveness at ``now``; over the legacy arrays (``exp`` None,
+    liveness baked into the run set) it is ``core.query``'s ``scan`` or
+    ``gather_view``. The outputs are packed into one flat uint32 array —
+    key words, value words, validity as 0/1, the view position the
+    window starts at and, with ``rows`` (the index tier, whose values
+    stay on the host), each slot's run id and row — so the host makes
+    one fetch (:func:`unpack_window`) where it would make one per
+    output."""
+    words, now = at[:, :-1], at[0, -1]
+    pos = words[:, 0].astype(jnp.int32)
+    if exp is None:
+        if seek:
+            keys, vals, valid, pos = Q.scan(
+                remix, runset, words, width=width,
+                ingroup=Q.ingroup_for_backend(),
+            )
+        else:
+            keys, vals, valid = Q.gather_view(remix, runset, pos, width)
+        run_rows = ()
+    else:
+        from repro.kernels import ops
+
+        if seek:
+            keys, vals, valid, rid, row, pos = ops.scan_live(
+                remix, runset, exp, words, now, width=width,
+                interpret=interpret,
+            )
+        else:
+            keys, vals, valid, rid, row = ops.gather_view_live(
+                remix, runset, exp, pos, now, width, interpret=interpret,
+            )
+        run_rows = (rid, row) if rows else ()
     u32 = jnp.uint32
     return jnp.concatenate([
         keys.reshape(-1), vals.reshape(-1),
         valid.reshape(-1).astype(u32), pos.reshape(-1).astype(u32),
+        *(x.reshape(-1).astype(u32) for x in run_rows),
     ])
 
 
 def unpack_window(buf: np.ndarray, width: int, kw: int, vw: int):
     """One query's :func:`window_buffer`, fetched: views ``keys (W, KW)``,
-    ``vals (W, VW)``, ``valid (W,)`` bool and the start position."""
+    ``vals (W, VW)``, ``valid (W,)`` bool, the start position and the
+    slots' ``(run id, row)`` as a (2, W) int32 view (empty without
+    ``rows``)."""
     nk, nv = width * kw, width * vw
     keys = buf[:nk].reshape(width, kw)
     vals = buf[nk:nk + nv].reshape(width, vw)
-    valid = buf[nk + nv:nk + nv + width].astype(bool)
-    return keys, vals, valid, int(buf[nk + nv + width])
+    o = nk + nv + width
+    valid = buf[nk + nv:o].astype(bool)
+    return (keys, vals, valid, int(buf[o]),
+            buf[o + 1:].view(np.int32).reshape(-1, width))
+
+
+@dataclasses.dataclass
+class _DeviceStream:
+    """A promoted partition's table-entry stream: its device view (None:
+    the legacy read path), the clock it opened at, the arrays its
+    windows read (taken at the first: the view's, or ``p.index()``'s
+    with liveness baked at that instant) and its saved view position."""
+
+    p: object
+    view: object | None
+    now: int
+    arrays: tuple | None = None
+    pos: int | None = None
 
 
 class RemixCursor:
@@ -235,62 +290,72 @@ class RemixCursor:
         seek (cold: anchors + bounded CKB; promoted: the device seek,
         fused with the first window), after which every window is a pure
         position advance."""
+        store = self.store
         with _tracing.span("route"):
             p = self.snap.partitions[self._pi]
             lo, _ = self._spans[self._pi]
             start = max(self._start, lo) if self._first else lo
             self._first = False
             self._width = self.base_width
-            cold = self.store._cold_ok(p)
+            cold = store._cold_ok(p)
+            dv = None if cold else store._device_view(p)
         if cold:
             self._stream = ("cold", p, p.cold_cursor_seek(start))
             return
-        self._stream = ["dev", p, None, None, None]
+        self._stream = _DeviceStream(p, dv, int(clock.now()))
         self._pulled = self._device_window(start)
 
     def _device_window(self, start: int | None = None):
         """One window of the promoted stream: one launch and one fetch.
-        A stream with no position yet seeks ``start`` in the same call.
+        A stream with no position yet seeks ``start`` in the same call;
+        every window reads at the ``now`` the stream opened at.
         Returns (keys u64, vals, partition_done)."""
         stream, store = self._stream, self.store
-        _, p, remix, runset, pos = stream
-        mod, kw = store._query_mod(), store._qkw()
+        dv = stream.view
+        rows = dv is not None and dv.tier != "full"
         with _tracing.span("launch"):
-            if pos is None:
-                remix, runset = p.index()
-                stream[2:4] = remix, runset
-                op = mod.scan
+            if stream.arrays is None:
+                stream.arrays = ((dv.remix, dv.runset, dv.exp)
+                                 if dv is not None
+                                 else (*stream.p.index(), None))
+            remix, runset, exp = stream.arrays
+            seek = stream.pos is None
+            if seek:
                 at = CK.pack_u64(np.array([start], np.uint64))
                 store._c_cursor_seeks.inc()
             else:
-                op = mod.gather_view
-                at = np.array([pos], np.int32)
-                if not store.cfg.use_kernels:
-                    kw = {}  # the reference gather has no in-group search
+                at = np.array([[stream.pos]], np.uint32)
+            at = np.append(at, np.uint32(stream.now))[None]
             # whole groups: the decode covers ceil(width / d) + 1 groups
             # whatever the width, so this adds no device work, and one
             # program per group count (not per width) keeps the fused
             # seek's compiles few
             width = -(-self._width // remix.d) * remix.d
-            buf_d = window_buffer(remix, runset, at, op=op, width=width,
-                                  opts=tuple(kw.items()))
+            buf_d = window_buffer(
+                remix, runset, exp, at, width=width, seek=seek, rows=rows,
+                interpret=dv is not None and store.device_views.interpret,
+            )
             store._c_cursor_windows.inc()
             store._c_launches.inc()
         (buf,) = _tracing.fetch(store._c_syncs, buf_d)
         with _tracing.span("unpack"):
             del buf_d  # frees the device buffer
-            keys, vals, valid, pos = unpack_window(
+            keys, vals, valid, pos, run_rows = unpack_window(
                 buf, width, runset.keys.shape[-1], runset.vals.shape[-1],
             )
-            kk, vv, clipped = self._clip(CK.unpack_u64(keys[valid]),
-                                         vals[valid])
-            stream[4] = pos + width
-        return kk, vv, clipped or stream[4] >= remix.n_slots
+            if rows:
+                rid, row = run_rows
+                vals = dv.host_vals(rid[valid], row[valid])
+            else:
+                vals = vals[valid]
+            kk, vv, clipped = self._clip(CK.unpack_u64(keys[valid]), vals)
+            stream.pos = pos + width
+        return kk, vv, clipped or stream.pos >= remix.n_slots
 
     def _next_window(self):
         """One window of live table entries from the current partition.
         Returns (keys u64, vals, partition_done)."""
-        if self._stream[0] == "cold":
+        if not isinstance(self._stream, _DeviceStream):
             _, p, state = self._stream
             kk, vv, more = p.cold_cursor_window(
                 state, self._width,

@@ -1,30 +1,34 @@
 """Key-range partitions: table files + one REMIX per partition (paper §4).
 
 Tables are host numpy arrays (the "files") or lazy on-disk handles; the
-partition lazily builds its REMIX + stacked RunSet (jnp, device-resident)
-when first queried. Partitions are *logically immutable* once published
+partition builds its REMIX from the tables' keys at compaction
+(:meth:`Partition.host_remix`), and a padded device copy of the REMIX and
+the stacked runs only when a read needs one
+(:meth:`Partition.device_index`, the device view's;
+:meth:`Partition.index`, the legacy read path's). Partitions are
+*logically immutable* once published
 in a :class:`repro.db.version.Version`: compaction never mutates a live
 partition's table list — it derives a successor via
 :meth:`Partition.clone_with_tables` (sharing unchanged table handles and
 the built REMIX as the incremental-rebuild base), mirroring the paper's
 "new version of the partition includes ... a new REMIX file" with the old
-version still servable by pinned readers. The query caches (``index()``,
-host view) are benign fills shared across versions — they never change
-query results, only where they are answered from.
+version still servable by pinned readers. The query caches (the built
+REMIX, ``index()``, host view) are benign fills shared across versions —
+they never change query results, only where they are answered from.
 """
 from __future__ import annotations
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import keys as CK
-from repro.core.remix import Remix, build_remix
+from repro.core.remix import Remix, remix_from_keys
 from repro.core.runs import (
-    Run,
     RunSet,
     RowWindow,
-    make_run,
     merge_ranges_np,
     ranges_to_rows,
 )
@@ -41,47 +45,47 @@ def _pow2(n: int, lo: int = 1) -> int:
     return b
 
 
-def _pad_index(remix: Remix, runset: RunSet, d: int) -> tuple[Remix, RunSet]:
-    """Pad (G, R, Nmax) to power-of-two buckets; query semantics unchanged
-    (pad groups are all-placeholder with +inf anchors, pad runs are empty)."""
+def _pad_index(remix: Remix, tabs: list, cover, with_vals: bool):
+    """Host numpy ``(remix, runset, exp)`` of a device copy, padded to
+    power-of-two buckets (G, R, Nmax) so every partition of a store shares
+    the same compiled query executables; query semantics unchanged (pad
+    groups are all-placeholder with +inf anchors, pad runs are empty).
+
+    The tombstone column carries real tombstones plus ``cover(t)``, the
+    rows of ``t`` an excised span hides (structural: a covered row can
+    never revive); TTL expiry words (0 = none) ride along in ``exp``.
+    Without ``with_vals`` the run set carries 1-word dummy values."""
+    d = remix.d
     g2 = _pow2(remix.g, 4)
-    r2 = _pow2(remix.r, 1)
-    n2 = _pow2(runset.nmax, 64)
-    if (g2, r2, n2) == (remix.g, remix.r, runset.nmax):
-        return remix, runset
-    anchors = np.full((g2, runset.kw), 0xFFFFFFFF, np.uint32)
+    r2 = _pow2(len(tabs), 1)
+    n2 = _pow2(max(t.n for t in tabs), 64)
+    vw = tabs[0].vw if with_vals else 1
+    anchors = np.full((g2, CK.KW), 0xFFFFFFFF, np.uint32)
     anchors[: remix.g] = np.asarray(remix.anchors)
     cursors = np.zeros((g2, r2), np.int32)
     cursors[: remix.g, : remix.r] = np.asarray(remix.cursors)
     selectors = np.full((g2 * d,), PLACEHOLDER, np.uint8)
     selectors[: remix.n_slots] = np.asarray(remix.selectors)
-    keys = np.full((r2, n2, runset.kw), 0xFFFFFFFF, np.uint32)
-    keys[: runset.r, : runset.nmax] = np.asarray(runset.keys)
-    vals = np.zeros((r2, n2, runset.vw), np.uint32)
-    vals[: runset.r, : runset.nmax] = np.asarray(runset.vals)
+    keys = np.full((r2, n2, CK.KW), 0xFFFFFFFF, np.uint32)
+    vals = np.zeros((r2, n2, vw), np.uint32)
     seq = np.zeros((r2, n2), np.uint32)
-    seq[: runset.r, : runset.nmax] = np.asarray(runset.seq)
     tomb = np.zeros((r2, n2), bool)
-    tomb[: runset.r, : runset.nmax] = np.asarray(runset.tomb)
     lens = np.zeros((r2,), np.int32)
-    lens[: runset.r] = np.asarray(runset.lens)
-    import jax.numpy as jnp
-
+    exp = np.zeros((r2, n2), np.uint32)
+    for i, t in enumerate(tabs):
+        n = lens[i] = t.n
+        keys[i, :n] = CK.pack_u64(t.keys)
+        if with_vals:
+            vals[i, :n] = t.vals
+        seq[i, :n] = t.seq
+        tomb[i, :n] = np.asarray(t.tomb, bool) | cover(t)
+        if t.ttl_present():
+            exp[i, :n] = t.exp
     return (
-        Remix(
-            anchors=jnp.asarray(anchors),
-            cursors=jnp.asarray(cursors),
-            selectors=jnp.asarray(selectors),
-            n_entries=remix.n_entries,
-            d=d,
-        ),
-        RunSet(
-            keys=jnp.asarray(keys),
-            vals=jnp.asarray(vals),
-            seq=jnp.asarray(seq),
-            tomb=jnp.asarray(tomb),
-            lens=jnp.asarray(lens),
-        ),
+        Remix(anchors=anchors, cursors=cursors, selectors=selectors,
+              n_entries=remix.n_entries, d=d),
+        RunSet(keys=keys, vals=vals, seq=seq, tomb=tomb, lens=lens),
+        exp,
     )
 
 
@@ -396,15 +400,6 @@ class Table:
         e = self.rows_scattered("exp", rows)
         return tomb | ((e != 0) & (e <= np.uint32(int(now))))
 
-    def min_future_exp(self, now: float) -> int | None:
-        """Smallest TTL expiry still in the future, or None: the instant
-        a device index built at ``now`` goes stale."""
-        if not self.ttl_present():
-            return None
-        e = self.exp
-        fut = e[(e != 0) & (e > np.uint32(int(now)))]
-        return int(fut.min()) if fut.size else None
-
     @property
     def n(self) -> int:
         if self._n is None:  # header-only read; no section is loaded
@@ -558,14 +553,15 @@ class Partition:
         self.lo = int(lo)  # inclusive lower bound of the key range
         self.tables: list[Table] = tables or []
         self.d = d
+        # the legacy read path's padded device copy (index())
         self._remix: Remix | None = None
         self._runset: RunSet | None = None
         self.remix_bytes = 0  # last REMIX build size (for WA accounting)
         # committed range tombstones covering (subsets of) self.tables
         self.excised: list[ExcisedSpan] = []
-        # earliest future TTL expiry baked into the built device index:
-        # past this instant the runset's tomb marks are stale and index()
-        # rebuilds them (REMIX structure is unaffected by liveness)
+        # earliest future TTL expiry baked into index()'s run set: past
+        # this instant its tomb marks are stale and index() rebuilds them
+        # (the REMIX structure is unaffected by liveness)
         self._ttl_next: float | None = None
         # last built (unpadded) REMIX + the tables it covered: a minor
         # compaction that only appends tables rebuilds incrementally from
@@ -573,7 +569,7 @@ class Partition:
         self._built_remix: Remix | None = None
         self._built_tables: list[Table] = []
         self.remix_name: str | None = None  # manifest name when persisted
-        self.last_build_kind = "none"  # none | scratch | incremental | reuse
+        self.last_build_kind = "none"  # none | scratch | incremental
         # cold read path: host-side view of the (preloaded) REMIX + counters
         self._host: dict | None = None
         self.cold_gets = 0
@@ -663,7 +659,7 @@ class Partition:
 
     def preload_index(self, remix: Remix):
         """Adopt a deserialized REMIX for the current table list (recovery
-        path): the next ``index()`` reuses it instead of rebuilding."""
+        path): :meth:`host_remix` reuses it instead of rebuilding."""
         self._built_remix = remix
         self._built_tables = list(self.tables)
         self.remix_bytes = int(remix.storage_bytes())
@@ -1244,16 +1240,48 @@ class Partition:
     def data_bytes(self) -> int:
         return sum(t.bytes() for t in self.tables)
 
-    def index(self) -> tuple[Remix, RunSet]:
-        """Build (or reuse) the partition's REMIX + stacked runs.
+    def _tables_or_empty(self) -> list[Table]:
+        return self.tables or [
+            Table(
+                keys=np.zeros(0, np.uint64),
+                vals=np.zeros((0, 2), np.uint32),
+                seq=np.zeros(0, np.uint32),
+                tomb=np.zeros(0, bool),
+            )
+        ]
 
-        Shapes are bucket-padded to powers of two so every partition of a
-        store shares the same compiled query executables (shape-stable
-        kernels — one jit per bucket instead of one per partition).
-        """
-        # TTL staleness: tomb marks were baked at build time; once the
-        # clock passes the earliest future expiry, rebuild the runset
-        # (the REMIX itself is liveness-independent and gets reused)
+    def host_remix(self) -> Remix:
+        """Build (or reuse) the unpadded REMIX over the current tables —
+        what compaction sizes (``remix_bytes``), ``persist_index`` writes
+        and cold reads walk — from the tables' keys and sequence numbers
+        alone: no value is read and no device run set is built."""
+        tabs = self._tables_or_empty()
+        built = self._built_tables if self.tables else []
+        if (
+            self._built_remix is not None
+            and len(built) == len(self.tables)
+            and all(a is b for a, b in zip(built, self.tables))
+        ):
+            return self._built_remix
+        d = max(self.d, len(tabs))  # paper requires D >= R
+        remix = self._try_incremental(tabs, d)
+        if remix is None:
+            remix = remix_from_keys(
+                [t.key_words() for t in tabs], [t.seq for t in tabs], d
+            )
+            self.last_build_kind = "scratch"
+        self._built_remix = remix
+        self._built_tables = list(self.tables)
+        self.remix_bytes = int(remix.storage_bytes())
+        return remix
+
+    def index(self) -> tuple[Remix, RunSet]:
+        """The legacy device copy ``(remix, runset)`` for the reads no
+        device view serves: :meth:`device_index`'s build with the TTL
+        expiries folded into the tombstone column at the build's ``now``
+        (tombstones, expired rows and excised rows: the set the cold path
+        and the views evaluate at the same instant). Once the clock
+        passes the earliest future expiry the run set is rebuilt."""
         if (
             self._remix is not None
             and self._ttl_next is not None
@@ -1262,46 +1290,21 @@ class Partition:
             self._remix = None
             self._runset = None
         if self._remix is None:
-            tabs = self.tables or [
-                Table(
-                    keys=np.zeros(0, np.uint64),
-                    vals=np.zeros((0, 2), np.uint32),
-                    seq=np.zeros(0, np.uint32),
-                    tomb=np.zeros(0, bool),
-                )
-            ]
-            d = max(self.d, len(tabs))  # paper requires D >= R
-            now = clock.now()
-            runs = [
-                make_run(t.keys, t.vals, seq=t.seq,
-                         tomb=self._build_dead(t, now), sort=False)
-                for t in tabs
-            ]
-            nexts = [t.min_future_exp(now) for t in tabs]
-            self._ttl_next = min(
-                (x for x in nexts if x is not None), default=None
+            now = np.uint32(int(clock.now()))
+            remix, runset, exp = _pad_index(
+                self.host_remix(), self._tables_or_empty(),
+                self._span_cover, with_vals=True,
             )
-            remix = self._try_incremental(tabs, d)
-            if remix is not None:
-                from repro.core.runs import stack_runs
-
-                runset = stack_runs(runs)
-            else:
-                remix, runset = build_remix(runs, d=d)
-                self.last_build_kind = "scratch"
-            self._built_remix = remix
-            self._built_tables = list(tabs) if self.tables else []
-            self.remix_bytes = int(remix.storage_bytes())
-            self._remix, self._runset = _pad_index(remix, runset, d)
+            ttl = exp != 0
+            fut = exp[ttl & (exp > now)]
+            self._ttl_next = int(fut.min()) if fut.size else None
+            runset = dataclasses.replace(
+                runset, tomb=runset.tomb | (ttl & (exp <= now))
+            )
+            self._remix, self._runset = jax.tree_util.tree_map(
+                jnp.asarray, (remix, runset)
+            )
         return self._remix, self._runset
-
-    def _build_dead(self, t: Table, now: float) -> np.ndarray:
-        """Liveness column baked into the device runset for table ``t``:
-        tombstones, TTL-expired rows, and rows an excised span covers.
-        Exact for point/scan results: a covered or expired newest version
-        decodes as a tombstone slot, and any newer uncovered version
-        lives in a later-born table the span doesn't cover."""
-        return t.dead(now) | self._span_cover(t)
 
     def _span_cover(self, t: Table) -> np.ndarray:
         """(N,) bool: rows of ``t`` hidden by an excised span covering it
@@ -1331,67 +1334,29 @@ class Partition:
         return int(g2 * (4 * kw + 4 * r2 + d) + r2 * n2 * per_row + r2 * 4)
 
     def device_index(self, with_vals: bool = True):
-        """Padded ``(remix, runset, exp)`` for the device-resident view.
+        """Padded device ``(remix, runset, exp)`` of the resident view:
+        the one construction of a partition's device copy.
 
-        Unlike :meth:`index`, liveness is *not* baked at build time: the
-        runset tombstones carry only real tombstones plus excised-span
-        coverage (structural), and the per-row TTL expiry words ride
-        along as a padded (R, Nmax) uint32 array so the device evaluates
-        ``tomb | (exp != 0 & exp <= now)`` at query time — bit-for-bit
-        the :meth:`_build_dead` set at the same instant, and a persistent
-        view never goes stale when the clock passes an expiry.
+        Liveness is *not* baked at build time: the run set's tombstones
+        carry only real tombstones plus excised-span coverage
+        (structural), and the per-row TTL expiry words ride along as a
+        padded (R, Nmax) uint32 array, so the device evaluates
+        ``tomb | (exp != 0 & exp <= now)`` at query time and a
+        persistent view never goes stale when the clock passes an
+        expiry.
 
         With ``with_vals=False`` (the index-only residency tier) the
-        value sections stay host-side: the runset carries 1-word dummy
-        values and callers gather real value granules through the
-        BlockCache from the returned (run, row) coordinates.
-
-        Shares the REMIX structure cache (``_built_remix`` /
-        incremental rebuilds) with :meth:`index` — the structure is
-        liveness-independent, so the two paths reuse each other's build.
+        value sections stay host-side: the run set carries 1-word dummy
+        values and callers gather real values from the returned (run,
+        row) coordinates (``DeviceView.host_vals``).
         """
-        tabs = self.tables or [
-            Table(
-                keys=np.zeros(0, np.uint64),
-                vals=np.zeros((0, 2), np.uint32),
-                seq=np.zeros(0, np.uint32),
-                tomb=np.zeros(0, bool),
-            )
-        ]
-        d = max(self.d, len(tabs))  # paper requires D >= R
-        runs, exps = [], []
-        for t in tabs:
-            dead = np.asarray(t.tomb, bool) | self._span_cover(t)
-            vals = t.vals if with_vals else np.zeros((t.n, 1), np.uint32)
-            runs.append(
-                make_run(t.keys, vals, seq=t.seq, tomb=dead, sort=False)
-            )
-            exps.append(
-                np.asarray(t.exp, np.uint32)
-                if t.ttl_present()
-                else np.zeros(t.n, np.uint32)
-            )
-        remix = self._try_incremental(tabs, d)
-        if remix is not None:
-            from repro.core.runs import stack_runs
-
-            runset = stack_runs(runs)
-        else:
-            remix, runset = build_remix(runs, d=d)
-            self.last_build_kind = "scratch"
-        self._built_remix = remix
-        self._built_tables = list(tabs) if self.tables else []
-        self.remix_bytes = int(remix.storage_bytes())
-        remix_p, runset_p = _pad_index(remix, runset, d)
-        exp_p = np.zeros((runset_p.r, runset_p.nmax), np.uint32)
-        for i, e in enumerate(exps):
-            exp_p[i, : len(e)] = e
-        import jax.numpy as jnp
-
-        return remix_p, runset_p, jnp.asarray(exp_p)
+        return jax.tree_util.tree_map(jnp.asarray, _pad_index(
+            self.host_remix(), self._tables_or_empty(), self._span_cover,
+            with_vals=with_vals,
+        ))
 
     def _try_incremental(self, tabs: list[Table], d: int) -> Remix | None:
-        """Reuse/extend the last built REMIX when this rebuild only appended
+        """Extend the last built REMIX when this rebuild only appended
         tables (minor compaction) — zero key comparisons among old runs.
 
         Returns None when the table set changed in any other way (major,
@@ -1401,13 +1366,10 @@ class Partition:
         prev, base = self._built_remix, self._built_tables
         if prev is None or not base or prev.r != len(base) or prev.d != d:
             return None
-        if len(tabs) < len(base) or any(
+        if len(tabs) <= len(base) or any(
             a is not b for a, b in zip(base, tabs)
         ):
             return None
-        if len(tabs) == len(base):  # nothing changed: reuse as-is
-            self.last_build_kind = "reuse"
-            return prev
         from repro.io.rebuild import incremental_build_remix
 
         new = tabs[len(base):]
@@ -1423,9 +1385,9 @@ class Partition:
 
     def persist_index(self, storage) -> None:
         """Build (if needed) and serialize this partition's REMIX; the
-        padded on-device copy is derived, only the unpadded index persists."""
-        self.index()
-        self.remix_name = storage.write_remix(self._built_remix)
+        padded device copies are derived, only the unpadded index
+        persists."""
+        self.remix_name = storage.write_remix(self.host_remix())
 
     def estimate_remix_bytes(self, extra_entries: int = 0) -> int:
         """Size estimate of a REMIX over current + new entries (§4.2 Abort)."""

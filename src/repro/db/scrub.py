@@ -23,8 +23,6 @@ import dataclasses
 import os
 import time
 
-import numpy as np
-
 from repro.io.faults import CorruptionError
 
 
@@ -173,15 +171,10 @@ def rebuild_remix(tables, d: int = 32):
     bytes are read; the returned :class:`repro.core.remix.Remix` is
     servable cold and byte-compatible with ``dump_remix``.
     """
-    from repro.core.remix import build_remix
-    from repro.core.runs import make_run
+    from repro.core.remix import remix_from_keys
 
-    runs = []
-    for t in tables:
-        kw = np.asarray(t.key_words(), np.uint32)  # prefers the CKB
-        runs.append(make_run(
-            kw, None, seq=np.asarray(t.seq), tomb=np.asarray(t.tomb),
-            vw=t.vw, sort=False,
-        ))
-    remix, _ = build_remix(runs, d=max(int(d), len(runs) or 1))
-    return remix
+    return remix_from_keys(
+        [t.key_words() for t in tables],  # prefers the CKB
+        [t.seq for t in tables],
+        max(int(d), len(tables) or 1),
+    )

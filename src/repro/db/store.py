@@ -75,6 +75,12 @@ from repro.obs.metrics import MetricsRegistry, merge_snapshots
 
 @dataclasses.dataclass
 class RemixDBConfig:
+    """Store settings. A promoted partition's reads take its device view
+    where ``device_path`` engages views and a residency tier fits
+    ``device_budget_bytes``; otherwise the legacy ``Partition.index()``
+    copy, read by the pure-JAX ``core.query`` with the in-group search the
+    backend picks."""
+
     vw: int = 2  # value words (uint32)
     d: int = 32  # REMIX group size
     memtable_entries: int = 1 << 18
@@ -83,10 +89,6 @@ class RemixDBConfig:
         default_factory=CompactionConfig
     )
     wal_dir: str | None = None
-    use_kernels: bool = False  # route queries through the Pallas kernel path
-    # in-group search mode: "auto" picks binary probes on CPU (gathers are
-    # scalar-expensive) and the vectorized all-slot compare on TPU (§Perf)
-    ingroup: str = "auto"
     # persistence root: when set, flushes write SSTables + REMIX files there
     # and commit a manifest; RemixDB.open(dir) recovers the store from it
     data_dir: str | None = None
@@ -105,16 +107,20 @@ class RemixDBConfig:
     # decision inputs are exposed in stats()["cache"]["promotion"]
     promote_fraction: float = 0.5
     # ---- device-resident query execution (docs/ARCHITECTURE.md) ----
-    # promoted-partition read routing: "auto" answers promoted reads
-    # from persistent device views when a real accelerator backend is
-    # attached; "on" forces the device path everywhere (on CPU the
-    # kernels run in Pallas interpret mode — the CI parity
-    # configuration); "off" keeps the legacy jitted host-array path
+    # promoted-partition read routing: "auto" answers promoted reads —
+    # gets, batched scans and the cursor's windows — from persistent
+    # device views when a real accelerator backend is attached; "on"
+    # forces the device path everywhere (on CPU the kernels run in
+    # Pallas interpret mode — the CI parity configuration); "off" keeps
+    # the legacy path: a padded ``Partition.index()`` copy read by the
+    # pure-JAX core.query (in-group search by backend: binary on CPU,
+    # vector elsewhere)
     device_path: str = "auto"
     # HBM byte budget for resident device views (LRU-evicted under
     # upload pressure; views whose partition left every live Version
     # are dropped at release). A partition that fits neither residency
-    # tier falls back to the legacy path (device_fallback_total)
+    # tier falls back to the legacy path (device_fallback_total), so a
+    # promoted partition holds one padded device copy either way
     device_budget_bytes: int = 256 << 20
     # batch-slice width of the host/device overlapped value pipeline
     # (index-only tier: the device resolves row windows for slice i+1
@@ -239,23 +245,9 @@ def partition_entry_renamed(pe: dict, rename=None) -> dict:
 class RemixDB:
     def __init__(self, config: RemixDBConfig | None = None):
         self.cfg = config or RemixDBConfig()
-        # resolve the in-group search mode once; query paths only ever see
-        # a valid "binary"/"vector" (a stray "auto" would raise in seek)
-        mode = self.cfg.ingroup
-        if mode == "auto":
-            mode = "binary" if jax.default_backend() == "cpu" else "vector"
-        if mode not in ("binary", "vector"):
-            raise ValueError(
-                f"ingroup must be 'auto', 'binary' or 'vector', got {mode!r}"
-            )
-        self._ingroup = mode
-        # the legacy kernel path decides interpret mode once, like the
-        # device views do (raises on a backend with no kernel path)
-        self._kernel_interpret = None
-        if self.cfg.use_kernels:
-            from repro.kernels.ops import interpret_for_backend
-
-            self._kernel_interpret = interpret_for_backend()
+        # the legacy read path's in-group search, decided once from the
+        # backend (core.query.ingroup_for_backend)
+        self._ingroup = Q.ingroup_for_backend()
         if self.cfg.cache_mode not in ("copy", "mmap"):
             raise ValueError(
                 f"cache_mode must be 'copy' or 'mmap', "
@@ -374,7 +366,7 @@ class RemixDB:
         self._c_cas_conflict = reg.counter("cas_conflict")
         self._c_ttl_dropped = reg.counter("ttl_expired_dropped")
         self._c_rtomb_drop = reg.counter("range_tombstone_drop")
-        # the device boundary of the jitted reads over ``p.index()`` and
+        # the device boundary of the legacy reads over ``p.index()`` and
         # of the cursor; the device views count into the same series
         self._c_launches = reg.counter("device_launches")
         self._c_syncs = reg.counter("device_syncs")
@@ -1513,21 +1505,6 @@ class RemixDB:
         return cur
 
     # ---------------- read path ----------------
-    def _query_mod(self):
-        if self.cfg.use_kernels:
-            from repro.kernels import ops
-
-            return ops
-        return Q
-
-    def _qkw(self) -> dict:
-        """Per-backend query kwargs (§Perf: binary in-group probes win on
-        CPU, the vectorized all-slot compare wins on TPU). ``auto`` was
-        resolved once at construction; only valid modes reach seek."""
-        if self.cfg.use_kernels:
-            return dict(interpret=self._kernel_interpret)
-        return dict(ingroup=self._ingroup)
-
     def _device_view(self, p: Partition):
         """Resident device view for a promoted partition (uploaded on
         first use), or None — disabled, over budget, or ineligible —
@@ -1612,8 +1589,7 @@ class RemixDB:
         with _tracing.span("launch"):
             remix, runset = p.index()
             qk = jnp.asarray(CK.pack_u64(np.array([key], np.uint64)))
-            found, val = self._query_mod().get(remix, runset, qk,
-                                               **self._qkw())
+            found, val = Q.get(remix, runset, qk, ingroup=self._ingroup)
             self._c_launches.inc()
         (f,) = _tracing.fetch(self._c_syncs, found)
         if not f[0]:
@@ -1671,8 +1647,7 @@ class RemixDB:
                 pad = _pow2pad(len(kq))
                 kq = np.pad(kq, (0, pad - len(kq)))
                 qk = jnp.asarray(CK.pack_u64(kq))
-                f, v = self._query_mod().get(remix, runset, qk,
-                                             **self._qkw())
+                f, v = Q.get(remix, runset, qk, ingroup=self._ingroup)
                 self._c_launches.inc()
             (f,) = _tracing.fetch(self._c_syncs, f)
             found[sel] = f[: len(sel)]
@@ -1854,22 +1829,19 @@ class RemixDB:
 
     def _index_windows(self, p: Partition, sel, starts, width: int,
                        with_vals: bool) -> list:
-        """One jitted window call over the partition's ``p.index()``
-        arrays for the queries ``sel``: ``(qi, keys, vals)`` rows of live
-        entries."""
+        """One jitted window call over the partition's legacy
+        ``p.index()`` arrays for the queries ``sel``: ``(qi, keys, vals)``
+        rows of live entries. Without ``with_vals`` (e.g. scan_batch) the
+        value gather is skipped (XLA dead-code-eliminates it)."""
         with _tracing.span("launch"):
             remix, runset = p.index()
             sq = starts[sel]
             pad = _pow2pad(len(sq))
             sq = np.pad(sq, (0, pad - len(sq)))
             qk = jnp.asarray(CK.pack_u64(sq))
-            kw = dict(self._qkw())
-            if not self.cfg.use_kernels:
-                # skip the value gather (XLA dead-code-eliminates it)
-                # when the caller only needs keys, e.g. scan_batch
-                kw["with_vals"] = with_vals
-            keys, vals, valid, _ = self._query_mod().scan(
-                remix, runset, qk, width=width, **kw
+            keys, vals, valid, _ = Q.scan(
+                remix, runset, qk, width=width, ingroup=self._ingroup,
+                with_vals=with_vals,
             )
             self._c_launches.inc()
         (keys,) = _tracing.fetch(self._c_syncs, keys)

@@ -4,7 +4,13 @@ partitions plus the fused batched execution driver (ROADMAP item: the
 
 A :class:`DeviceView` holds one promoted partition's REMIX structural
 arrays (anchors, selector stream, cursor offsets) and its stacked run
-sections as device buffers, in one of two residency tiers:
+sections as device buffers — the partition's one device copy, built by
+:meth:`repro.db.partition.Partition.device_index`. Every promoted read of
+the partition goes through it: batched gets
+(:meth:`DeviceViewManager.get_batch`), batched scans
+(:meth:`DeviceViewManager.scan_windows`) and the cursor's windows
+(:func:`repro.db.cursor.window_buffer`, every lone Seek+NextN).
+It lives in one of two residency tiers:
 
 - ``full``  — keys, values, tombstones and TTL expiry words all resident:
   a batched get/scan is one jitted Pallas composition (seek → selector
@@ -20,8 +26,9 @@ sections as device buffers, in one of two residency tiers:
 Liveness is evaluated at query time on device: uploaded tombstone words
 carry real tombstones plus excised-span coverage (structural, can never
 revive), and per-row TTL expiry words are compared against a traced
-``now`` — bit-for-bit the host path's `_build_dead` set at the same
-instant, with no rebuild when the clock passes an expiry.
+``now`` — bit-for-bit the dead set the legacy ``Partition.index()``
+copy bakes in at the same instant, with no rebuild when the clock
+passes an expiry.
 
 The :class:`DeviceViewManager` owns an HBM byte budget: LRU eviction on
 upload pressure, and release-time eviction tied to the VersionSet pin
@@ -37,7 +44,9 @@ to the device ride in the call's own dispatch; ``device_launches``),
 ``device_wait`` (:func:`repro.obs.tracing.fetch`, one blocking
 device→host transfer; ``device_syncs``) and ``unpack`` (host work on
 the fetched result). A point lookup's result is one packed buffer
-(:func:`repro.kernels.ops.unpack_get`).
+(:func:`repro.kernels.ops.unpack_get`), as is a cursor window's. The
+index tier's values come from the host tables by the (run, row) a call
+resolved (:meth:`DeviceView.host_vals`).
 ``benchmarks/kernels_bench.py`` asserts the fused batch-256 get pipeline
 pays exactly one sync per batch.
 """
@@ -83,6 +92,16 @@ class DeviceView:
     @property
     def tables(self):
         return self.partition.tables
+
+    def host_vals(self, rid: np.ndarray, row: np.ndarray) -> np.ndarray:
+        """(N, VW) values of the rows ``(rid, row)`` a device call
+        resolved, gathered from the host tables (the index tier's value
+        plane): one scattered, granule-deduped fetch per touched run."""
+        vals = np.zeros((len(rid), self.vw), np.uint32)
+        for r in np.unique(rid):
+            m = rid == r
+            vals[m] = self.tables[r].rows_scattered("vals", row[m])
+        return vals
 
 
 def _view_nbytes(remix, runset, exp) -> int:
@@ -226,9 +245,7 @@ class DeviceViewManager:
             found, vals, rid, row = ops.unpack_get(buf, pad, q)
             if dv.tier != "full":
                 vals = np.zeros((q, dv.vw), np.uint32)
-                for r in np.unique(rid[found]):
-                    m = found & (rid == r)
-                    vals[m] = dv.tables[r].rows_scattered("vals", row[m])
+                vals[found] = dv.host_vals(rid[found], row[found])
             self._c_rows.inc(int(found.sum()))
         return found, vals
 
@@ -315,15 +332,9 @@ class DeviceViewManager:
                 nq = min(s, q - si * s)
                 keys, valid = keys[:nq], valid[:nq]
                 rid, row = rid[:nq], row[:nq]
-                # slice value gather: group live rows per run, one
-                # scattered (granule-deduped) fetch per touched table
+                # slice value gather: one scattered fetch per touched run
                 vals = np.zeros((nq, width, dv.vw), np.uint32)
-                rid_f, row_f = rid[valid], row[valid]
-                gath = np.zeros((len(rid_f), dv.vw), np.uint32)
-                for r in np.unique(rid_f):
-                    m = rid_f == r
-                    gath[m] = dv.tables[r].rows_scattered("vals", row_f[m])
-                vals[valid] = gath
+                vals[valid] = dv.host_vals(rid[valid], row[valid])
                 for i in range(nq):
                     m = valid[i]
                     kk = CK.unpack_u64(keys[i][m])

@@ -62,74 +62,16 @@ def seek(
         return jnp.minimum(g * d + s, remix.n_slots)
 
 
-@partial(jax.jit, static_argnames=("width", "interpret"))
-def gather_view(
-    remix: Remix,
-    runset: RunSet,
-    pos: jnp.ndarray,
-    width: int,
-    *,
-    interpret: bool,
-):
-    """Kernel-backed comparison-free range retrieval from view positions."""
-    d = remix.d
-    q = pos.shape[0]
-    ng = (width + d - 1) // d + 1
-    with jax.named_scope("remix_gather"):
-        g0 = jnp.clip(pos // d, 0, remix.g - 1)
-        gs = g0[:, None] + jnp.arange(ng, dtype=jnp.int32)[None, :]
-        gsc = jnp.clip(gs, 0, remix.g - 1)
-        sels = remix.selectors.reshape(remix.g, d)[gsc].reshape(q * ng, d)
-        curs = remix.cursors[gsc].reshape(q * ng, remix.r)
-        runid, absidx, newest, pad = selector_decode(
-            sels, curs, r=remix.r, interpret=interpret
-        )
-        keys, vals, _, tomb = runset.gather(runid, absidx)
-        keys = jnp.where(pad[..., None], K.UINT32_MAX, keys)
-
-        def reshape_q(x):
-            return x.reshape((q, ng * d) + x.shape[2:])
-
-        off = pos - g0 * d
-
-        def slice_one(x, o):
-            return jax.lax.dynamic_slice_in_dim(x, o, width, axis=0)
-
-        take = lambda x: jax.vmap(slice_one)(reshape_q(x), off)
-        keys, vals = take(keys), take(vals)
-        newest, pad, tomb = take(newest), take(pad), take(tomb)
-    with jax.named_scope("liveness"):
-        gslot = pos[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
-        valid = newest & ~pad & ~tomb & (gslot < remix.n_slots)
-    return keys, vals, valid
-
-
-@partial(jax.jit, static_argnames=("width", "interpret"))
-def scan(remix, runset, queries, width: int, *, interpret: bool):
-    pos = seek(remix, runset, queries, interpret=interpret)
-    return (*gather_view(remix, runset, pos, width, interpret=interpret), pos)
-
-
-@partial(jax.jit, static_argnames=("interpret",))
-def get(remix, runset, queries, *, interpret: bool):
-    queries = jnp.asarray(queries, jnp.uint32)
-    pos = seek(remix, runset, queries, interpret=interpret)
-    keys, vals, valid = gather_view(remix, runset, pos, 1, interpret=interpret)
-    with jax.named_scope("liveness"):
-        found = valid[:, 0] & K.key_eq(keys[:, 0], queries)
-    return found, vals[:, 0]
-
-
-# ---- device-resident live variants (kernels/device_view.py) ----
+# ---- the device views' compositions (kernels/device_view.py) ----
 #
-# Same pipeline, but liveness is *not* baked into the runset tombstones:
-# per-row TTL expiry words ride along as a (R, Nmax) uint32 array and the
-# window applies `tomb | (exp != 0 & exp <= now)` with `now` a traced
-# scalar — so a persistent device view never goes stale when the clock
-# passes an expiry (the host path rebuilds its runset instead). The
-# resolved (run, row) coordinates are returned alongside so the index-only
-# residency tier can gather value granules host-side (BlockCache) from the
-# same single device round trip.
+# Liveness is *not* baked into the runset tombstones: per-row TTL expiry
+# words ride along as a (R, Nmax) uint32 array and the window applies
+# `tomb | (exp != 0 & exp <= now)` with `now` a traced scalar — so a
+# persistent device view never goes stale when the clock passes an
+# expiry (the legacy `p.index()` copy rebuilds its runset instead). The
+# resolved (run, row) coordinates are returned alongside so the
+# index-only residency tier can gather value granules host-side
+# (BlockCache) from the same single device round trip.
 
 
 @partial(jax.jit, static_argnames=("width", "interpret"))
@@ -143,7 +85,9 @@ def gather_view_live(
     *,
     interpret: bool,
 ):
-    """`gather_view` with query-time liveness + (run, row) emission."""
+    """Comparison-free range retrieval of ``width`` view slots from each
+    position in ``pos`` (``core.query.gather_view``'s contract), with
+    liveness evaluated at ``now`` and each slot's (run, row) emitted."""
     d = remix.d
     q = pos.shape[0]
     ng = (width + d - 1) // d + 1

@@ -6,8 +6,10 @@ launch, one fetch of one packed buffer) and pulls every later window
 from the saved view position (again one launch, one fetch). These tests
 hold scans and streaming cursors over several partitions, MemTable
 overlay entries, point tombstones and range tombstones to a dict model
-of the live entries, for both query modules (the pure-JAX reference
-``core.query`` and the Pallas kernels, interpreted on the CPU).
+of the live entries, for both promoted read paths: the legacy
+``p.index()`` copy read by ``core.query`` (``device_path="off"``) and the
+budgeted device view read by the Pallas compositions (``"on"``,
+interpreted on the CPU).
 """
 import bisect
 
@@ -18,8 +20,8 @@ from repro.db.compaction import CompactionConfig
 from repro.db.store import RemixDB, RemixDBConfig
 
 LENGTHS = (1, 2, 9, 33, 64, 100, 150)
-MODULES = [False, True]
-MODULE_IDS = ["core_query", "kernels"]
+PATHS = ["off", "on"]
+PATH_IDS = ["legacy", "view"]
 
 
 class Model:
@@ -54,7 +56,7 @@ def _vals(keys, version):
     return np.stack([lo, np.full(len(keys), version, np.uint32)], 1)
 
 
-@pytest.fixture(scope="module", params=MODULES, ids=MODULE_IDS)
+@pytest.fixture(scope="module", params=PATHS, ids=PATH_IDS)
 def store(request, tmp_path_factory):
     """A store of several promoted partitions, with flushed point and
     range tombstones, older versions under newer ones, and a MemTable
@@ -62,7 +64,7 @@ def store(request, tmp_path_factory):
     cfg = RemixDBConfig(
         memtable_entries=2048,
         compaction=CompactionConfig(table_cap=128, t_max=3, split_m=2),
-        hot_threshold=255, cold_reads=False, use_kernels=request.param,
+        hot_threshold=255, cold_reads=False, device_path=request.param,
     )
     db = RemixDB.open(str(tmp_path_factory.mktemp("db")), cfg)
     model = Model()

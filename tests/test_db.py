@@ -229,28 +229,3 @@ def test_write_amplification_ordering(tmp_path):
     wa_lv = lv.write_amplification()
     wa_tr = tr.write_amplification()
     assert wa_tr < wa_db < wa_lv, (wa_tr, wa_db, wa_lv)
-
-
-def test_kernel_query_path_matches_default(tmp_path):
-    """``use_kernels`` answers through the Pallas ops (interpret mode on
-    CPU, decided once from the backend) with the default path's results."""
-    rng = np.random.default_rng(5)
-    keys = rng.choice(100_000, size=2000, replace=False).astype(np.uint64)
-    vals = np.stack([keys & 0xFFFFFFFF, keys >> 32], axis=1).astype(np.uint32)
-    dbs = [
-        RemixDB(small_cfg(tmp_path / str(k), use_kernels=k, device_path="off"))
-        for k in (False, True)
-    ]
-    probe = np.concatenate([keys[:300], np.arange(100_001, 100_009,
-                                                  dtype=np.uint64)])
-    out = []
-    for db in dbs:
-        db.put_batch(keys, vals)
-        db.flush()
-        found, got = db.get_batch(probe)
-        kk, vv = db.scan(int(np.sort(keys)[700]), 80)
-        bk, bm = db.scan_batch(probe[:16], 20)
-        out.append((found, got[found], kk, vv, bk[bm], bm))
-    assert dbs[1]._kernel_interpret is True
-    for a, b in zip(*out):
-        np.testing.assert_array_equal(a, b)

@@ -362,3 +362,96 @@ def test_lone_get_one_launch_one_fetch(get_store, monkeypatch):
     assert delta == [1, 1]
     assert fetched == [1]
     np.testing.assert_array_equal(v, model.get(key, GET_T0))
+
+
+# ------------------------------- one device copy per promoted partition
+def test_views_leave_the_legacy_copy_unbuilt(tmp_path, monkeypatch):
+    """With views on, gets, lone and batched scans, streaming cursors,
+    flushes and every kind of compaction never build a partition's
+    legacy padded copy (``Partition.index``); each persisted REMIX is
+    the one a scratch build over the partition's runs gives."""
+    from repro.core.remix import build_remix
+    from repro.core.runs import make_run
+    from repro.db.partition import Partition
+    from repro.io.remix_io import load_remix
+
+    calls = []
+    real = Partition.index
+    monkeypatch.setattr(Partition, "index",
+                        lambda self: calls.append(self) or real(self))
+    db = RemixDB.open(str(tmp_path / "db"), _cfg(
+        device_path="on", cold_reads=False,
+        compaction=CompactionConfig(table_cap=128, t_max=3, split_m=2),
+    ))
+    model: dict[int, list] = {}
+    rng = np.random.default_rng(21)
+    try:
+        for rnd in range(8):
+            keys = rng.choice(1 << 16, 160, replace=False).astype(np.uint64)
+            vals = np.stack([keys & np.uint64(0xFFFF),
+                             np.full(len(keys), rnd, np.uint64)], 1)
+            db.put_batch(keys, vals.astype(np.uint32))
+            model.update({int(k): [int(k) & 0xFFFF, rnd] for k in keys})
+            db.flush()
+            live = sorted(model)
+            probe = rng.choice(live, 24).astype(np.uint64)
+            found, got = db.get_batch(probe)
+            assert found.all()
+            np.testing.assert_array_equal(got, [model[k] for k in
+                                                probe.tolist()])
+            for s in probe[:4].tolist():
+                kk, _ = db.scan(s, 40)
+                i = live.index(s)
+                np.testing.assert_array_equal(kk, live[i:i + 40])
+            kk, mm = db.scan_batch(np.sort(probe), 9)
+            assert mm.any()
+            with db.cursor(start=0, width=4) as cur:
+                kk, _ = cur.next_batch(300)
+            np.testing.assert_array_equal(kk, live[:300])
+        assert {"minor", "split"} <= db._comp_kinds
+        assert calls == []
+        assert len(db.partitions) > 1
+        assert all(p._remix is None for p in db.partitions)
+        assert len(db.device_views) > 0
+        for p in db.partitions:
+            want, _ = build_remix(
+                [make_run(t.keys, t.vals, seq=t.seq, sort=False)
+                 for t in p.tables],
+                d=max(db.cfg.d, len(p.tables)),
+            )
+            got = load_remix(db.storage.remix_path(p.remix_name))
+            for f in ("anchors", "cursors", "selectors", "n_entries"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(got, f)), np.asarray(getattr(want, f)),
+                    err_msg=f,
+                )
+    finally:
+        db.close()
+
+
+def test_view_cursor_reads_at_its_opening_now(get_store):
+    """A stream over a view (either tier) answers at the clock it opened
+    at: TTL rows that expire between two of its windows still come back,
+    as the reference gives them at the opening instant."""
+    db, dv, model, _ = get_store
+    opened, later = GET_T0 - 1, GET_T0 + 5
+    names = ("cursor_seeks", "cursor_windows", "device_fallback_total")
+    s0 = [_metric(db, n) for n in names]
+    clock.set_source(lambda: opened)
+    try:
+        with db.cursor(start=0, width=4) as cur:
+            first = cur.next_batch(1)
+            clock.set_source(lambda: later)
+            rest = cur.next_batch(1000)
+    finally:
+        clock.reset()
+    seeks, windows, fallback = (_metric(db, n) - v for n, v in zip(names, s0))
+    assert (seeks, fallback) == (1, 0) and windows >= 2
+    assert db.partitions[0]._remix is None  # the view answered
+    kk = np.concatenate([first[0], rest[0]])
+    vv = np.concatenate([first[1], rest[1]])
+    want = model.items(opened)
+    np.testing.assert_array_equal(kk, [k for k, _ in want])
+    np.testing.assert_array_equal(vv, [v for _, v in want])
+    # some of what the later windows returned had expired by then
+    assert any(model.get(k, later) is None for k in rest[0].tolist())

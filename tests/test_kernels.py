@@ -114,25 +114,33 @@ def _runset(rng, r=6, n=300, space=4000, d=32):
 
 @pytest.mark.parametrize("d", [16, 32, 64])
 def test_ops_seek_get_scan_match_reference(d):
+    """The Pallas seek and the views' fused lookup and scan (no TTL rows,
+    so ``now`` hides nothing) answer as the pure-JAX reference does."""
     rng = np.random.default_rng(d)
     remix, runset = _runset(rng, d=d)
+    exp = jnp.zeros(runset.tomb.shape, jnp.uint32)
+    now = np.uint32(1)
     queries = rng.integers(0, 4100, size=200).astype(np.uint64)
     qk = jnp.asarray(K.pack_u64(queries))
     np.testing.assert_array_equal(
         np.asarray(ops.seek(remix, runset, qk, interpret=True)),
         np.asarray(Q.seek(remix, runset, qk)),
     )
-    f1, v1 = ops.get(remix, runset, qk, interpret=True)
+    f1, v1, _, _ = ops.unpack_get(
+        np.asarray(ops.get_live(remix, runset, exp, qk, now,
+                                interpret=True)), len(queries), len(queries))
     f2, v2 = Q.get(remix, runset, qk)
-    np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
-    np.testing.assert_array_equal(
-        np.asarray(v1)[np.asarray(f1)], np.asarray(v2)[np.asarray(f2)]
-    )
-    k1, vv1, va1, _ = ops.scan(remix, runset, qk[:32], width=50, interpret=True)
+    np.testing.assert_array_equal(f1, np.asarray(f2))
+    np.testing.assert_array_equal(v1[f1], np.asarray(v2)[np.asarray(f2)])
+    k1, vv1, va1, *_ = ops.scan_live(remix, runset, exp, qk[:32], now,
+                                     width=50, interpret=True)
     k2, vv2, va2, _ = Q.scan(remix, runset, qk[:32], width=50)
     np.testing.assert_array_equal(np.asarray(va1), np.asarray(va2))
     np.testing.assert_array_equal(
         np.asarray(k1)[np.asarray(va1)], np.asarray(k2)[np.asarray(va2)]
+    )
+    np.testing.assert_array_equal(
+        np.asarray(vv1)[np.asarray(va1)], np.asarray(vv2)[np.asarray(va2)]
     )
 
 

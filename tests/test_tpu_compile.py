@@ -8,9 +8,10 @@ width 75 (Seek+Next50 plus the store's window slack), group size 32,
 16 padded runs of 65536 rows and 32768 groups per partition. The
 cursor's packed windows (``db.cursor.window_buffer``: the seek fused
 with the first window, and a later window from a saved position) are
-compiled for one query at widths 75 and 150, over both query modules
-(the kernels, and ``core.query``, which a store without
-``use_kernels`` runs on the chip). Nothing
+compiled as the cursor launches them over a device view — one query and
+one packed uint32 host argument carrying ``now`` — at widths 75 and 150,
+for both residency tiers (``full``, and ``index``, whose values stay on
+the host and whose window also returns each slot's run and row). Nothing
 runs; the compiler refuses what the chip would refuse (VMEM overruns,
 unsupported primitives, misaligned tiles), which interpret mode cannot
 show. The topology is described inside a fixture so that only the test
@@ -24,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import query as Q
 from repro.core.remix import Remix
 from repro.core.runs import RunSet
 from repro.db.cursor import window_buffer
@@ -84,14 +84,14 @@ def test_selector_decode_compiles(one_chip):
     )
 
 
-def _view(sharding):
+def _view(sharding, vw=VW):
     s = lambda shape, dt: _sds(sharding, shape, dt)  # noqa: E731
     remix = Remix(
         anchors=s((G, KW), jnp.uint32), cursors=s((G, R), jnp.int32),
         selectors=s((G * D,), jnp.uint8), n_entries=s((), jnp.int32), d=D,
     )
     runset = RunSet(
-        keys=s((R, NMAX, KW), jnp.uint32), vals=s((R, NMAX, VW), jnp.uint32),
+        keys=s((R, NMAX, KW), jnp.uint32), vals=s((R, NMAX, vw), jnp.uint32),
         seq=s((R, NMAX), jnp.uint32), tomb=s((R, NMAX), jnp.bool_),
         lens=s((R,), jnp.int32),
     )
@@ -118,23 +118,18 @@ def test_fused_get_and_scan_compile(one_chip):
 
 @pytest.mark.parametrize("width", [SCAN_WIDTH, 2 * SCAN_WIDTH])
 @pytest.mark.parametrize("seek", [True, False], ids=["fused_open", "window"])
-@pytest.mark.parametrize("kernels", [True, False],
-                         ids=["kernels", "core_query"])
-def test_cursor_window_buffer_compiles(one_chip, width, seek, kernels):
-    remix, runset, _, _ = _view(one_chip)
-    if kernels:
-        mod, opts = ops, (("interpret", False),)
-    else:
-        mod, opts = Q, (("ingroup", "vector"),) if seek else ()
-    if seek:
-        op, at = mod.scan, _sds(one_chip, (1, KW), jnp.uint32)
-    else:
-        op, at = mod.gather_view, _sds(one_chip, (1,), jnp.int32)
-    _compile_has_kernel(
-        lambda rm, rs, a: window_buffer(rm, rs, a, op=op, width=width,
-                                        opts=opts),
-        remix, runset, at, kernel=kernels,
-    )
+@pytest.mark.parametrize("tier", ["full", "index"])
+def test_cursor_window_buffer_compiles(one_chip, width, seek, tier):
+    rows = tier == "index"
+    remix, runset, exp, _ = _view(one_chip, vw=1 if rows else VW)
+    # the one host argument: the start key's words (or the position),
+    # then the stream's now
+    at = _sds(one_chip, (1, KW + 1 if seek else 2), jnp.uint32)
+    lowered = window_buffer.lower(remix, runset, exp, at, width=width,
+                                  seek=seek, rows=rows, interpret=False)
+    words = KW + runset.vals.shape[-1] + 1 + 2 * rows
+    assert lowered.out_info.shape == (width * words + 1,)
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 @pytest.mark.parametrize("q,width", [(32, 32), (32, 160), (64, 160)])
